@@ -56,6 +56,8 @@ def main():
     os.environ["XLA_FLAGS"] = (f"{flags} --xla_force_host_platform_"
                                f"device_count={args.nodes}").strip()
     sys.path.insert(0, os.path.join(REPO, "src"))
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import dataclasses
 

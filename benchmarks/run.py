@@ -6,11 +6,17 @@
 Prints ``name,us_per_call,derived`` CSV rows per benchmark plus the
 figure-level tables the paper reports.  Roofline terms come from the
 dry-run artifacts (results/*.jsonl) — see §Roofline in EXPERIMENTS.md.
+
+One process per chip: asked for several benchmarks, the driver runs
+each in a child process of its own and never imports JAX itself, so
+every benchmark — and the pool/autoscale workers it starts — can take
+the chip.
 """
 from __future__ import annotations
 
 import json
 import os
+import subprocess
 import sys
 import time
 
@@ -926,7 +932,6 @@ def pool_serving(out_path="BENCH_pool.json", quick=False,
     the uninterrupted run.  CPU simulation numbers measure the
     mechanism (one jitted step per token, LSE-merged partials), not
     TPU perf."""
-    import subprocess
     import sys as _sys
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -1405,6 +1410,15 @@ def main() -> None:
                          "(repro.core.faults.load_plan)")
     args = ap.parse_args()
     which = args.benches or list(BENCHES)
+    if len(which) > 1:
+        flags = ["--fault-plan", args.fault_plan] + (
+            ["--quick"] if args.quick else [])
+        for name in which:
+            subprocess.run([sys.executable, os.path.abspath(__file__), name]
+                           + flags, check=True)
+        return
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name in which:
         print(f"== {name} " + "=" * (66 - len(name)))

@@ -73,10 +73,13 @@ class ExtentStore:
     page-granular allocations out of a free list (mirroring λFS block
     allocation); the kernel addresses them through per-extent page
     tables, so extents never need to be physically contiguous.
+    ``device`` pins the pool to one device (a pool node's own chip);
+    None leaves it on the default device.
     """
 
     def __init__(self, *, n_pages: int = 64, page_rows: int = 128,
-                 n_cols: int = 128, page_dtype: str = "fp32"):
+                 n_cols: int = 128, page_dtype: str = "fp32",
+                 device=None):
         if page_dtype not in PAGE_DTYPES:
             raise ValueError(f"page_dtype must be one of {PAGE_DTYPES}, "
                              f"got {page_dtype!r}")
@@ -94,12 +97,13 @@ class ExtentStore:
             self.code_dtype, self.qmax = _fp8_dtype(), 448.0
         else:
             self.code_dtype, self.qmax = jnp.float32, 0.0
-        self.pages = jnp.zeros((n_pages, page_rows, n_cols),
-                               self.code_dtype)
+        self.pages = jax.device_put(
+            jnp.zeros((n_pages, page_rows, n_cols), self.code_dtype), device)
         # per-row scales of a quantized pool (1.0 keeps untouched pages
         # dequantizing to zero); None for full precision
-        self.scales = (jnp.ones((n_pages, page_rows), jnp.float32)
-                       if self.quantized else None)
+        self.scales = (jax.device_put(
+            jnp.ones((n_pages, page_rows), jnp.float32), device)
+            if self.quantized else None)
         self.extents: Dict[str, Extent] = {}
         self._free: List[int] = list(range(n_pages))
 

@@ -16,6 +16,7 @@ import json
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.container import MiniDocker, to_jsonable
@@ -39,7 +40,7 @@ class DockerSSDNode:
     """One disaggregated computational SSD."""
 
     def __init__(self, ip: str, spec: Optional[NodeSpec] = None,
-                 extent_cfg: Optional[Dict[str, int]] = None):
+                 extent_cfg: Optional[Dict[str, int]] = None, device=None):
         self.ip = ip
         # default must be constructed per node: a shared NodeSpec instance
         # would alias every node's spec, so mutating one (e.g. a degraded
@@ -49,8 +50,9 @@ class DockerSSDNode:
         self.fs = LambdaFS(capacity_bytes=int(spec.flash_gb * 1e9))
         self.endpoint = DockerSSDEndpoint(ip)
         self.fw = VirtualFW(self.fs, self.endpoint)
-        # flash-resident analytics pages, addressed by the scan kernel
-        self.extents = ExtentStore(**(extent_cfg or {}))
+        # flash-resident analytics pages, addressed by the scan kernel,
+        # on this node's own device
+        self.extents = ExtentStore(**(extent_cfg or {}), device=device)
         self.docker = MiniDocker(self.fw, self.fs, extents=self.extents)
         # λFS lock syncs ride the pool's Ether-oN driver
         self.alive = True
@@ -529,11 +531,14 @@ class StoragePool:
         """Provision node ``i``: wired into the Ether-oN fabric, λFS lock
         syncs attached, and slotted into its array (array topology follows
         the pool's configured ``array_size``).  Each node gets its own
-        NodeSpec copy — per-node state never aliases across the pool."""
+        NodeSpec copy — per-node state never aliases across the pool —
+        and keeps its extent pages on device ``i`` (mod the device
+        count), the device that hosts pool shard ``i``'s KV window."""
         ip = f"10.0.{1 + i // self.array_size}.{2 + i % self.array_size}"
+        devices = jax.devices()
         node = DockerSSDNode(
             ip, dataclasses.replace(spec) if spec is not None else None,
-            extent_cfg=self.extent_cfg)
+            extent_cfg=self.extent_cfg, device=devices[i % len(devices)])
         node.fs.attach_ether(self.driver)
         self.nodes[ip] = node
         self.driver.attach(node.endpoint)

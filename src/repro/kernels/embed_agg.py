@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams
 
 
 def _embed_kernel(idx_ref, w_ref, table_ref, o_ref, *, weighted: bool):
@@ -84,7 +83,7 @@ def embed_agg(table, indices, weights=None, *, interpret: bool = False):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="embed_agg",
@@ -119,7 +118,7 @@ def embed_gather(table, indices, *, interpret: bool = False):
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * kk, d), table.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="embed_gather",

@@ -1,7 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute under ``interpret=True``;
-on TPU they compile natively.  ``ref.py`` holds the pure-jnp oracles.
+On the CPU backend (tests, ``JAX_PLATFORMS=cpu`` rehearsals) the kernels
+execute under ``interpret=True``; on TPU they compile natively; any
+other backend raises (see :func:`interpret_mode`).  ``ref.py`` holds
+the pure-jnp oracles.
 """
 from __future__ import annotations
 
@@ -23,8 +25,16 @@ from repro.kernels.paged_attention import (paged_attention as _paged,
 from repro.kernels.rwkv_scan import rwkv_scan as _rwkv
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run interpreted: on the ``cpu`` backend
+    yes, on ``tpu`` no.  Any other backend has no lowering for these
+    TPU kernels, and silently interpreting them there would hide the
+    device, so it raises."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas TPU kernels need the 'tpu' backend "
+                           f"(or 'cpu' to interpret them), got {backend!r}")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
@@ -32,7 +42,7 @@ def _interpret_default() -> bool:
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
                     block_k: int = 128, interpret: bool | None = None):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     return _flash(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
                   interpret=interpret)
 
@@ -41,7 +51,7 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
 def paged_attention(q, k_pages, v_pages, page_table, lengths,
                     interpret: bool | None = None):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     return _paged(q, k_pages, v_pages, page_table, lengths,
                   interpret=interpret)
 
@@ -50,7 +60,7 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths,
 def paged_attention_q8(q, k_pages, v_pages, k_scale, v_scale, page_table,
                        lengths, interpret: bool | None = None):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     return _paged_q8(q, k_pages, v_pages, k_scale, v_scale, page_table,
                      lengths, interpret=interpret)
 
@@ -65,7 +75,7 @@ def embed_agg(table, indices, weights=None, interpret: bool | None = None):
     vocab ids raise instead of silently clamping), then the jitted kernel
     runs."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     indices = jnp.asarray(indices)
     validate_embed_args(table, indices)
     return _embed_agg_jit(table, indices, weights, interpret)
@@ -108,7 +118,7 @@ def scan_filter_reduce(pages, page_table, n_rows, threshold=0.0, *,
     kernel dequantizes in VMEM — see ``kernels.isp_scan``).
     Returns [8, n_cols] f32 — see ``kernels.isp_scan`` for the layout."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     pt = _pow2_pad_table(jnp.asarray(page_table, jnp.int32).reshape(-1))
     nr = jnp.asarray(n_rows, jnp.int32).reshape(1)
     th = jnp.asarray(threshold, jnp.float32).reshape(1)
@@ -146,7 +156,7 @@ def topk_scan(pages, page_table, n_rows, query, *, k: int,
     ids row 1; empty slots hold (-1e30, 2^30).  Only this block ever
     crosses the wire — the retrieval wire-reduction story."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     n_cols = pages.shape[2]
     pt = _pow2_pad_table(jnp.asarray(page_table, jnp.int32).reshape(-1))
     nr = jnp.asarray(n_rows, jnp.int32).reshape(1)
@@ -172,7 +182,7 @@ def embed_gather(table, indices, interpret: bool | None = None):
     """Validating wrapper over the batched [B, K] row gather (eager
     bounds check like ``embed_agg``, then one jit per shape bucket)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     indices = jnp.asarray(indices)
     validate_embed_args(table, indices)
     return _embed_gather_jit(table, indices, interpret)
@@ -182,5 +192,5 @@ def embed_gather(table, indices, interpret: bool | None = None):
 def rwkv_scan(r, k, v, logw, u, s0, chunk: int = 32,
               interpret: bool | None = None):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     return _rwkv(r, k, v, logw, u, s0, chunk=chunk, interpret=interpret)

@@ -7,10 +7,21 @@ the kernel streams pages HBM->VMEM via scalar-prefetch index maps,
 accumulating an online softmax *at the page* — compute moves to the
 data, the data never moves to the query.
 
-Grid: (batch, kv_heads, pages_per_seq); the page axis is sequential so
-the per-(b,h) accumulators persist in VMEM scratch.  Pages whose start
-offset is beyond the sequence length are skipped entirely (pl.when), so
-work scales with actual context length, not table capacity.
+Grid: (batch, pages_per_seq); the page axis is sequential so the
+per-sequence accumulators persist in VMEM scratch.  One grid step DMAs
+one physical page with **all** its KV heads — the block ``(1, page,
+Hkv, D)`` spans the array's last two dims, which is what the TPU's
+(8, 128) block tiling rule accepts for any head count, head dim, page
+size and dtype — and the kernel loops over the heads in VMEM.  Pages
+whose start offset is beyond the sequence length are skipped (pl.when),
+so compute scales with actual context length, not table capacity; so
+are pages whose table entry is negative (not owned by this pool node —
+the distributed contract below).
+
+``return_stats=True`` also returns the online-softmax statistics (row
+max ``m`` and denominator ``l``) next to the normalized output: the
+partial form pool nodes merge across the mesh
+(``runtime.serve.combine_partials``).
 
 Calling convention: the batched serving path holds *stacked* pages
 ``[n_layers, hbm_pages, page, Hkv, D]`` (core.kv_tier.PageStore) and
@@ -31,16 +42,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams
-
 NEG_INF = -1e30
 
 
-def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, page: int, n_pages_per_seq: int,
-                  sm_scale: float):
+def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *refs, page: int,
+                  n_pages_per_seq: int, sm_scale: float, hkv: int,
+                  quantized: bool, stats: bool):
+    refs = list(refs)
+    ks_ref, vs_ref = (refs.pop(0), refs.pop(0)) if quantized else (None,
+                                                                   None)
+    o_ref = refs.pop(0)
+    mo_ref, lo_ref = (refs.pop(0), refs.pop(0)) if stats else (None, None)
+    acc_ref, m_ref, l_ref = refs
     b = pl.program_id(0)
-    pi = pl.program_id(2)
+    pi = pl.program_id(1)
     length = len_ref[b]
 
     @pl.when(pi == 0)
@@ -49,165 +64,118 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(pi * page < length)
+    @pl.when((pi * page < length) & (pt_ref[b, pi] >= 0))
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)                  # [G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # [page, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * sm_scale                                     # [G, page]
-        pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+        live = pos < length                                  # [1, page]
+        for h in range(hkv):
+            q = q_ref[0, h].astype(jnp.float32)              # [G, D]
+            k = k_ref[0, :, h, :].astype(jnp.float32)        # [page, D]
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if quantized:
+                # int8/fp8 codes stream HBM->VMEM and dequantize
+                # in-register: HBM traffic is the quantized bytes
+                k = k * ks_ref[0, :, h:h + 1]                # [page, 1]
+                v = v * vs_ref[0, :, h:h + 1]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(live, s * sm_scale, NEG_INF)       # [G, page]
+            m_prev = m_ref[h]                                # [G, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(pi == n_pages_per_seq - 1)
     def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        if stats:
+            mo_ref[0] = m_ref[...]
+            lo_ref[0] = l_ref[...]
 
 
-def _paged_q8_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                     o_ref, acc_ref, m_ref, l_ref, *, page: int,
-                     n_pages_per_seq: int, sm_scale: float):
-    b = pl.program_id(0)
-    pi = pl.program_id(2)
-    length = len_ref[b]
+def _paged_call(q, k_pages, v_pages, scales, page_table, lengths, *,
+                interpret: bool, return_stats: bool, name: str):
+    b, h, d = q.shape
+    _, page, hkv, _ = k_pages.shape
+    pps = page_table.shape[1]
+    g = h // hkv
+    kernel = functools.partial(
+        _paged_kernel, page=page, n_pages_per_seq=pps,
+        sm_scale=1.0 / math.sqrt(d), hkv=hkv, quantized=bool(scales),
+        stats=return_stats)
 
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def page_spec(*tail):
+        # physical page id comes from the prefetched page table (a
+        # skipped, not-owned page still names a valid block); the block
+        # spans every KV head of the page
+        return pl.BlockSpec(
+            (1, page) + tail,
+            lambda bb, pi, pt, ln: (jnp.maximum(pt[bb, pi], 0), 0) +
+            (0,) * len(tail))
 
-    @pl.when(pi * page < length)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32)                  # [G, D]
-        # int8 pages stream HBM->VMEM; dequant happens in-register —
-        # HBM traffic is the int8 bytes (the §Perf opt2 realization)
-        kq = k_ref[0, :, 0, :].astype(jnp.float32)           # [page, D]
-        vq = v_ref[0, :, 0, :].astype(jnp.float32)
-        ks = ks_ref[0, :, 0].astype(jnp.float32)             # [page]
-        vs = vs_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, kq, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * ks[None, :] * sm_scale                       # fold k scale
-        pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        pw = p * vs[None, :]                                 # fold v scale
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            pw, vq, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+    def head_spec(last):
+        return pl.BlockSpec((1, hkv, g, last),
+                            lambda bb, pi, pt, ln: (bb, 0, 0, 0))
 
-    @pl.when(pi == n_pages_per_seq - 1)
-    def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+    out_specs = [head_spec(d)]
+    out_shape = [jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype)]
+    if return_stats:
+        out_specs += [head_spec(1), head_spec(1)]
+        out_shape += [jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32)] * 2
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, pps),
+        in_specs=[head_spec(d), page_spec(hkv, d), page_spec(hkv, d)] +
+                 [page_spec(hkv) for _ in scales],
+        out_specs=out_specs,
+        scratch_shapes=[
+            pltpu.VMEM((hkv, g, d), jnp.float32),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(page_table, lengths, q.reshape(b, hkv, g, d), k_pages, v_pages,
+      *[s.astype(jnp.float32) for s in scales])
+    if not return_stats:
+        return out[0].reshape(b, h, d)
+    o, m, l = out
+    return o.reshape(b, h, d), m.reshape(b, h), l.reshape(b, h)
 
 
 def paged_attention_q8(q, k_pages, v_pages, k_scale, v_scale, page_table,
-                       lengths, *, interpret: bool = False):
-    """int8-KV paged decode attention.
+                       lengths, *, interpret: bool = False,
+                       return_stats: bool = False):
+    """Quantized-KV paged decode attention (int8 or fp8 codes).
 
-    q: [B, H, D] float; k_pages/v_pages: int8 [n_pages, page, Hkv, D];
-    k_scale/v_scale: f32 [n_pages, page, Hkv]; page_table: [B, pps] int32;
-    lengths: [B].  Returns [B, H, D]."""
-    b, h, d = q.shape
-    n_phys, page, hkv, _ = k_pages.shape
-    pps = page_table.shape[1]
-    g = h // hkv
-    sm_scale = 1.0 / math.sqrt(d)
-    qg = q.reshape(b, hkv, g, d)
-
-    kernel = functools.partial(_paged_q8_kernel, page=page,
-                               n_pages_per_seq=pps, sm_scale=sm_scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, pps),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bb, hh, pi, pt, ln: (bb, hh, 0, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda bb, hh, pi, pt, ln: (pt[bb, pi], 0, hh, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda bb, hh, pi, pt, ln: (pt[bb, pi], 0, hh, 0)),
-            pl.BlockSpec((1, page, 1),
-                         lambda bb, hh, pi, pt, ln: (pt[bb, pi], 0, hh)),
-            pl.BlockSpec((1, page, 1),
-                         lambda bb, hh, pi, pt, ln: (pt[bb, pi], 0, hh)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bb, hh, pi, pt, ln: (bb, hh, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="paged_attention_q8",
-    )(page_table, lengths, qg, k_pages, v_pages, k_scale, v_scale)
-    return out.reshape(b, h, d)
+    q: [B, H, D] float; k_pages/v_pages: codes [n_pages, page, Hkv, D];
+    k_scale/v_scale: f32 [n_pages, page, Hkv]; page_table: [B, pps] int32
+    (negative = not owned, skipped); lengths: [B].  Returns [B, H, D],
+    or (o, m [B, H], l [B, H]) with ``return_stats``."""
+    return _paged_call(q, k_pages, v_pages, (k_scale, v_scale), page_table,
+                       lengths, interpret=interpret,
+                       return_stats=return_stats, name="paged_attention_q8")
 
 
 def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
-                    interpret: bool = False):
+                    interpret: bool = False, return_stats: bool = False):
     """q: [B, H, D]; k_pages/v_pages: [n_pages, page, Hkv, D];
-    page_table: [B, pages_per_seq] int32; lengths: [B] int32.
-    Returns [B, H, D]."""
-    b, h, d = q.shape
-    n_phys, page, hkv, _ = k_pages.shape
-    pps = page_table.shape[1]
-    g = h // hkv
-    sm_scale = 1.0 / math.sqrt(d)
-    qg = q.reshape(b, hkv, g, d)
-
-    kernel = functools.partial(_paged_kernel, page=page,
-                               n_pages_per_seq=pps, sm_scale=sm_scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, pps),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bb, hh, pi, pt, ln: (bb, hh, 0, 0)),
-            # physical page id comes from the prefetched page table
-            pl.BlockSpec((1, page, 1, d),
-                         lambda bb, hh, pi, pt, ln: (pt[bb, pi], 0, hh, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda bb, hh, pi, pt, ln: (pt[bb, pi], 0, hh, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bb, hh, pi, pt, ln: (bb, hh, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="paged_attention",
-    )(page_table, lengths, qg, k_pages, v_pages)
-    return out.reshape(b, h, d)
+    page_table: [B, pages_per_seq] int32 (negative = not owned, skipped);
+    lengths: [B] int32.  Returns [B, H, D], or (o, m [B, H], l [B, H])
+    with ``return_stats``."""
+    return _paged_call(q, k_pages, v_pages, (), page_table, lengths,
+                       interpret=interpret, return_stats=return_stats,
+                       name="paged_attention")

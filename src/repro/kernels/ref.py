@@ -95,46 +95,47 @@ def embed_agg_ref(table, indices, weights=None):
 # ---------------------------------------------------------------------------
 
 
+def _host_pages(data, page_rows: int):
+    """The extent as f32 pages [n_pages, page_rows, n_cols] (rows past
+    the end zero-padded) plus its page count."""
+    n_rows, n_cols = data.shape
+    n_pages = -(-max(n_rows, 1) // page_rows)
+    pad = n_pages * page_rows - n_rows
+    blocks = jnp.pad(data.astype(jnp.float32), ((0, pad), (0, 0))
+                     ).reshape(n_pages, page_rows, n_cols)
+    return blocks, n_pages
+
+
 def scan_filter_reduce_ref(data, page_rows: int, threshold=0.0, *,
                            filter_col: int = 0, filter_op: str = "all"):
     """Host-side reference for ``kernels.isp_scan.scan_filter_reduce``.
 
     data: [n_rows, n_cols] — the extent the host read back in full
     (the "host reads everything" baseline).  The fold walks pages of
-    ``page_rows`` rows sequentially with the *same* float32 ops and
-    order as the kernel, so the result is bit-identical to the
-    in-storage path — the offload correctness contract.
+    ``page_rows`` rows sequentially through the kernel's *own* page
+    fold (``fold_page`` / ``merge_partials``), so the result is
+    bit-identical to the in-storage path — the offload correctness
+    contract.
     Returns [8, n_cols] float32 (count/sum/min/max rows, zero padding).
     """
     from repro.kernels.isp_scan import (NEG_INF, POS_INF, REDUCE_ROWS,
-                                        _predicate)
+                                        fold_page, merge_partials)
     n_rows, n_cols = data.shape
-    n_pages = -(-max(n_rows, 1) // page_rows)
-    pad = n_pages * page_rows - n_rows
-    blocks = jnp.pad(data.astype(jnp.float32), ((0, pad), (0, 0))
-                     ).reshape(n_pages, page_rows, n_cols)
+    blocks, n_pages = _host_pages(data, page_rows)
     thresh = jnp.asarray(threshold, jnp.float32).reshape(())
 
-    def fold(carry, xs):
-        cnt, s, mn, mx = carry
+    def fold(acc, xs):
         pi, block = xs
-        pos = pi * page_rows + jnp.arange(page_rows, dtype=jnp.int32)[:, None]
-        key = block[:, filter_col:filter_col + 1]
-        mask = (pos < n_rows) & _predicate(key, thresh, filter_op)
-        cnt = cnt + jnp.sum(mask.astype(jnp.float32))
-        s = s + jnp.sum(jnp.where(mask, block, 0.0), axis=0)
-        mn = jnp.minimum(mn, jnp.min(jnp.where(mask, block, POS_INF), axis=0))
-        mx = jnp.maximum(mx, jnp.max(jnp.where(mask, block, NEG_INF), axis=0))
-        return (cnt, s, mn, mx), None
+        part = fold_page(block, pi, n_rows, thresh, page_rows=page_rows,
+                         filter_col=filter_col, filter_op=filter_op)
+        return merge_partials(acc, part), None
 
-    init = (jnp.zeros((), jnp.float32),
-            jnp.zeros((n_cols,), jnp.float32),
-            jnp.full((n_cols,), POS_INF, jnp.float32),
-            jnp.full((n_cols,), NEG_INF, jnp.float32))
-    (cnt, s, mn, mx), _ = lax.scan(
-        fold, init, (jnp.arange(n_pages, dtype=jnp.int32), blocks))
-    out = jnp.zeros((REDUCE_ROWS, n_cols), jnp.float32)
-    return out.at[0].set(cnt).at[1].set(s).at[2].set(mn).at[3].set(mx)
+    row = jnp.zeros((1, n_cols), jnp.float32)
+    init = (row, row, row + POS_INF, row + NEG_INF)
+    acc, _ = lax.scan(fold, init,
+                      (jnp.arange(n_pages, dtype=jnp.int32), blocks))
+    pad = jnp.zeros((REDUCE_ROWS - 4, n_cols), jnp.float32)
+    return jnp.concatenate(list(acc) + [pad], axis=0)
 
 
 def topk_scan_ref(data, query, *, page_rows: int, k: int,
@@ -143,33 +144,26 @@ def topk_scan_ref(data, query, *, page_rows: int, k: int,
 
     data: [n_rows, n_cols] — the extent the host read back in full.
     The fold walks pages sequentially and calls the kernel's *own*
-    page fold (``_topk_fold_page``), so scores, merge order, and
+    page fold (``topk_fold_page``), so scores, merge order, and
     tie-breaking (smallest row id wins equal scores) are bit-identical
     to the in-storage path by construction.
     Returns [8, topk_pad(k)] float32 (scores row 0, f32 ids row 1).
     """
-    from repro.kernels.isp_scan import (BIG_ID, NEG_INF, REDUCE_ROWS,
-                                        _topk_fold_page, topk_pad)
+    from repro.kernels.isp_scan import topk_block, topk_fold_page, topk_init
     n_rows, n_cols = data.shape
-    n_pages = -(-max(n_rows, 1) // page_rows)
-    pad = n_pages * page_rows - n_rows
-    blocks = jnp.pad(data.astype(jnp.float32), ((0, pad), (0, 0))
-                     ).reshape(n_pages, page_rows, n_cols)
+    blocks, n_pages = _host_pages(data, page_rows)
     q = jnp.asarray(query, jnp.float32).reshape(1, n_cols)
 
     def fold(carry, xs):
         acc_s, acc_i = carry
         pi, block = xs
-        return _topk_fold_page(block, pi, n_rows, q, acc_s, acc_i,
-                               page_rows=page_rows, k=k,
-                               metric=metric), None
+        return topk_fold_page(block, pi, n_rows, q, acc_s, acc_i,
+                              page_rows=page_rows, k=k,
+                              metric=metric), None
 
-    init = (jnp.full((1, k), NEG_INF, jnp.float32),
-            jnp.full((1, k), BIG_ID, jnp.float32))
     (acc_s, acc_i), _ = lax.scan(
-        fold, init, (jnp.arange(n_pages, dtype=jnp.int32), blocks))
-    out = jnp.zeros((REDUCE_ROWS, topk_pad(k)), jnp.float32)
-    return out.at[0, :k].set(acc_s[0]).at[1, :k].set(acc_i[0])
+        fold, topk_init(k), (jnp.arange(n_pages, dtype=jnp.int32), blocks))
+    return topk_block(acc_s, acc_i, k=k)
 
 
 # ---------------------------------------------------------------------------

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sT_ref,
@@ -92,7 +91,7 @@ def rwkv_scan(r, k, v, logw, u, s0, *, chunk: int = 32,
             jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="rwkv_scan",

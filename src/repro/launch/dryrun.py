@@ -174,8 +174,7 @@ def run_cell(arch_id: str, shape_name: str, mesh_name: str,
             mem = compiled.memory_analysis()
             print(mem)
             cost = compiled.cost_analysis()
-            print({k: v for k, v in (cost[0] if isinstance(cost, list)
-                                     else cost).items()
+            print({k: v for k, v in cost.items()
                    if k in ("flops", "bytes accessed")})
             terms = roofline.analyze(compiled, None, arch_id, shape_name,
                                      mesh_name, chips, meta["model_flops"])

@@ -5,9 +5,8 @@ importing this module never touches jax device state.  The dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
 import; smoke tests and benchmarks see the real (single) device.
 
-``auto_axis_kwargs`` smooths a jax API gap: ``AxisType`` /
-``axis_types=`` only exist in newer releases; on older jax every mesh
-axis is implicitly Auto, which is what we ask for anyway.
+``auto_axis_kwargs`` marks every mesh axis Auto (the sharding
+propagation mode these meshes are written for).
 """
 from __future__ import annotations
 
@@ -15,10 +14,7 @@ import jax
 
 
 def auto_axis_kwargs(axes) -> dict:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:          # older jax: all axes are Auto already
-        return {}
-    return {"axis_types": (axis_type.Auto,) * len(axes)}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * len(axes)}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import get_model
 from repro.runtime.serve import PagedServer, make_serving_fns
 
@@ -87,6 +88,7 @@ def main(argv=None):
                          "admission).  Prompts sharing a cached prefix "
                          "skip the covered pages entirely")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.speculative and not (args.paged or args.pool):
         raise SystemExit("--speculative needs --paged or --pool")

@@ -392,7 +392,6 @@ def apply_moe_shardmap(p, x, cfg, no_drop: bool = False):
     if m.empty or "model" not in m.axis_names:
         return apply_moe(p, x, cfg, no_drop=no_drop)
     from jax.sharding import PartitionSpec as P
-    from repro.jax_compat import shard_map_unchecked
     e, k = cfg.n_experts, cfg.top_k
     d, f = cfg.d_model, cfg.d_ff
     tp = m.shape["model"]
@@ -453,8 +452,8 @@ def apply_moe_shardmap(p, x, cfg, no_drop: bool = False):
         aux = lax.pmean(aux, fsdp)
         return out.reshape(xx.shape), aux
 
-    fn = shard_map_unchecked(local_fn, mesh=m, in_specs=(pspecs, xspec),
-                             out_specs=(xspec, P()))
+    fn = jax.shard_map(local_fn, mesh=m, in_specs=(pspecs, xspec),
+                       out_specs=(xspec, P()), check_vma=False)
     return fn({kk: p[kk] for kk in ("router", "w_gate", "w_up", "w_down")},
               x)
 

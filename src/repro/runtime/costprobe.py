@@ -38,8 +38,6 @@ KEYS = ("flops", "bytes", "coll")
 def _measure(lowered) -> Dict[str, float]:
     compiled = lowered.compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0]
     coll = roofline.collective_bytes(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),

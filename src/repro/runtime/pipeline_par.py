@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.jax_compat import shard_map_unchecked
-
 
 def make_pipeline_loss(model, mesh, *, n_microbatches: int,
                        stage_axis: str = "pod"):
@@ -144,9 +142,9 @@ def make_pipeline_loss(model, mesh, *, n_microbatches: int,
                 cnt = lax.psum(cnt, data_axes)
             return (nll / jnp.maximum(cnt, 1.0))[0]
 
-        fn = shard_map_unchecked(staged, mesh=mesh,
-                                 in_specs=(pspecs, bspec, bspec),
-                                 out_specs=P())
+        fn = jax.shard_map(staged, mesh=mesh,
+                           in_specs=(pspecs, bspec, bspec),
+                           out_specs=P(), check_vma=False)
         return fn(params, tokens, labels)
 
     return loss_fn

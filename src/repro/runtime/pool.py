@@ -36,19 +36,12 @@ from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.kv_tier import PageStore, PageTableManager
-from repro.jax_compat import shard_map_unchecked
-from repro.models import layers as L
 from repro.runtime import sharding as shd
-# the partial-softmax device contract lives with the serving bodies now
-# (the single-node fused horizon shares it); re-exported here for the
-# pool-facing name
-from repro.runtime.serve import (NEG_INF, PagedServer,  # noqa: F401
-                                 combine_partials, paged_attention_partial)
+from repro.runtime.serve import PagedServer, combine_partials
 
 POOL_AXIS = "model"
 
@@ -114,6 +107,9 @@ class PoolServer(PagedServer):
             hbm_pages_per_node = max(1, int(hbm_bytes_per_node) // pb)
         self.pages_per_node = hbm_pages_per_node
         self.policy = policy
+        # every DockerSSD holds the whole model: replicate the weights
+        # over the mesh once, not on every step
+        params = jax.device_put(params, NamedSharding(mesh, P()))
         self._placement: Dict[int, int] = {}
         self._dead: set = set()
         super().__init__(model, params, page_size=page_size,
@@ -121,13 +117,13 @@ class PoolServer(PagedServer):
                          dtype=dtype, prefix_cache=prefix_cache,
                          page_dtype=page_dtype)
         in_specs, out_specs = shd.pool_step_specs(self.quantized)
-        self._sharded_decode = shard_map_unchecked(
+        self._sharded_decode = jax.shard_map(
             self._decode_body, mesh=mesh, in_specs=in_specs,
-            out_specs=out_specs)
+            out_specs=out_specs, check_vma=False)
         chunk_in, chunk_out = shd.pool_chunk_specs(self.quantized)
-        self._sharded_chunk = shard_map_unchecked(
+        self._sharded_chunk = jax.shard_map(
             self._chunk_body, mesh=mesh, in_specs=chunk_in,
-            out_specs=chunk_out)
+            out_specs=chunk_out, check_vma=False)
         # shard_map'd horizon / speculative bodies, one per (static)
         # horizon length — bounded by the pow2 bucketing in
         # ``horizon_batch`` / ``spec_horizon_batch``
@@ -399,10 +395,9 @@ class PoolServer(PagedServer):
             # quantized stores dequantize in the partial itself (the
             # same multiply on every node), so the LSE merge stays
             # device-invariant across pool shards
-            acc, m, l = paged_attention_partial(
-                q, st["k"], st["v"], local_table, col_owned, new_lengths,
-                k_scale=st.get("ks"), v_scale=st.get("vs"))
-            return combine_partials(acc, m, l, POOL_AXIS).astype(self.dtype)
+            o, m, l = self._attention_partial(q, st, local_table,
+                                              col_owned, new_lengths)
+            return combine_partials(o, m, l, POOL_AXIS).astype(self.dtype)
 
         return append_target, attention
 
@@ -439,9 +434,10 @@ class PoolServer(PagedServer):
         fn = self._sharded_horizons.get(horizon)
         if fn is None:
             in_specs, out_specs = shd.pool_horizon_specs(self.quantized)
-            fn = shard_map_unchecked(
+            fn = jax.shard_map(
                 lambda *a: self._horizon_body(*a, horizon=horizon),
-                mesh=self.mesh, in_specs=in_specs, out_specs=out_specs)
+                mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+                check_vma=False)
             self._sharded_horizons[horizon] = fn
         return fn(params, state, page_table, lengths, tokens, budget,
                   eos_id, key, temperature, top_p, streams)
@@ -484,9 +480,10 @@ class PoolServer(PagedServer):
         fn = self._sharded_specs.get(horizon)
         if fn is None:
             in_specs, out_specs = shd.pool_spec_specs(self.quantized)
-            fn = shard_map_unchecked(
+            fn = jax.shard_map(
                 lambda *a: self._spec_body(*a, horizon=horizon),
-                mesh=self.mesh, in_specs=in_specs, out_specs=out_specs)
+                mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+                check_vma=False)
             self._sharded_specs[horizon] = fn
         return fn(params, state, page_table, lengths, tokens, budget,
                   eos_id, hist, hist_len, key, temperature, top_p,

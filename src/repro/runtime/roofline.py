@@ -1,11 +1,12 @@
 """Roofline analysis from compiled dry-run artifacts.
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16 / chip, 819 GB/s HBM / chip,
-~50 GB/s/link ICI.
+Hardware model: per-chip peaks keyed by ``jax.Device.device_kind``
+(``PEAKS``); a device kind missing from the table is an error, never a
+default.
 
-  compute term    = HLO_FLOPs            / (chips * PEAK_FLOPS)
-  memory term     = HLO_bytes            / (chips * HBM_BW)
-  collective term = collective_bytes     / (chips * ICI_BW)
+  compute term    = HLO_FLOPs            / (chips * peak FLOP/s)
+  memory term     = HLO_bytes            / (chips * HBM bytes/s)
+  collective term = collective_bytes     / (chips * ICI bytes/s/link)
 
 ``cost_analysis()`` supplies FLOPs/bytes; collective bytes are parsed
 out of the (post-SPMD) compiled HLO text by summing output operand sizes
@@ -18,9 +19,35 @@ import re
 from dataclasses import dataclass, asdict
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # bytes/s / chip
-ICI_BW = 50e9              # bytes/s / link
+
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    flops: float               # bf16 FLOP/s per chip
+    hbm_bw: float              # HBM bytes/s per chip
+    ici_bw: float              # ICI bytes/s per link
+
+
+#: Published per-chip peaks by ``device_kind``.  TPU v5e ("TPU v5
+#: lite"): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s of ICI per chip over 4 links (50 GB/s
+#: per link).
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+#: the chip the dry-run and probe launchers model their production
+#: meshes on (they compile on the host, so no device reports a kind)
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; raises for a chip not in ``PEAKS``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -85,18 +112,23 @@ class RooflineTerms:
     coll_breakdown: Dict[str, int]
     model_flops: float = 0.0           # 6*N(active)*D
     bytes_per_device: float = 0.0      # from memory_analysis
+    device_kind: str = TARGET_DEVICE_KIND
+
+    @property
+    def peaks(self) -> ChipPeaks:
+        return chip_peaks(self.device_kind)
 
     @property
     def compute_s(self) -> float:
-        return self.hlo_flops / (self.chips * PEAK_FLOPS)
+        return self.hlo_flops / (self.chips * self.peaks.flops)
 
     @property
     def memory_s(self) -> float:
-        return self.hlo_bytes / (self.chips * HBM_BW)
+        return self.hlo_bytes / (self.chips * self.peaks.hbm_bw)
 
     @property
     def collective_s(self) -> float:
-        return self.coll_bytes / (self.chips * ICI_BW)
+        return self.coll_bytes / (self.chips * self.peaks.ici_bw)
 
     @property
     def bottleneck(self) -> str:
@@ -120,7 +152,8 @@ class RooflineTerms:
         time (== MFU bound when compute-dominated)."""
         if self.step_time_s == 0:
             return 0.0
-        return (self.model_flops / self.step_time_s) / (self.chips * PEAK_FLOPS)
+        return ((self.model_flops / self.step_time_s) /
+                (self.chips * self.peaks.flops))
 
     def to_dict(self) -> dict:
         d = asdict(self)
@@ -133,10 +166,10 @@ class RooflineTerms:
 
 
 def analyze(compiled, lowered_text: Optional[str], arch: str, shape: str,
-            mesh_name: str, chips: int, model_flops: float) -> RooflineTerms:
+            mesh_name: str, chips: int, model_flops: float,
+            device_kind: str = TARGET_DEVICE_KIND) -> RooflineTerms:
+    chip_peaks(device_kind)
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     nbytes = float(cost.get("bytes accessed", 0.0))
     text = compiled.as_text() if lowered_text is None else lowered_text
@@ -151,7 +184,8 @@ def analyze(compiled, lowered_text: Optional[str], arch: str, shape: str,
     return RooflineTerms(arch=arch, shape=shape, mesh=mesh_name, chips=chips,
                          hlo_flops=flops, hlo_bytes=nbytes,
                          coll_bytes=float(total_coll), coll_breakdown=coll,
-                         model_flops=model_flops, bytes_per_device=bpd)
+                         model_flops=model_flops, bytes_per_device=bpd,
+                         device_kind=device_kind)
 
 
 def model_flops_estimate(n_active_params: int, tokens: int,

@@ -80,13 +80,13 @@ def paged_attention_partial(q, k_pages, v_pages, local_table, col_owned,
     """Paged decode attention returning online-softmax partials.
 
     The device contract of distributed paged attention (the pool hot
-    path): score only the pages this node owns, fold them with an
-    online softmax, and hand back the un-normalized state ``(acc, m,
-    l)`` so the caller can merge nodes exactly (``combine_partials``)
-    — or, on one node, normalize locally (the partial form *is* the
-    full softmax when every page is owned).  On TPU the Pallas
-    ``paged_attention`` kernel computes this piece per layer slice; the
-    partial form is the distributed contract either way.
+    path): score only the pages this node owns, fold them with a
+    softmax, and hand back ``(o, m, l)`` — the output normalized over
+    the owned pages plus its softmax statistics — so the caller can
+    merge nodes exactly (``combine_partials``); on one node owning
+    every page, ``o`` *is* the full attention.  This is the jnp form
+    (CPU interpret mode); on TPU the Pallas ``paged_attention`` kernel
+    computes the same contract per layer slice (``return_stats``).
 
     q: [B, H, D]; k_pages/v_pages: *local* [P_node, page, Hkv, D];
     local_table: [B, pps] local physical ids (garbage where not owned);
@@ -95,7 +95,7 @@ def paged_attention_partial(q, k_pages, v_pages, local_table, col_owned,
     ``k_scale``/``v_scale`` ([P_node, page, Hkv] f32, quantized stores
     only) dequantize in-register with the exact same multiply on every
     node, so the LSE merge stays device-invariant across pool shards.
-    Returns (acc [B, H, D] f32, m [B, H] f32, l [B, H] f32).
+    Returns (o [B, H, D] f32, m [B, H] f32, l [B, H] f32).
     """
     b, h, d = q.shape
     _, page, hkv, _ = k_pages.shape
@@ -124,28 +124,24 @@ def paged_attention_partial(q, k_pages, v_pages, local_table, col_owned,
     l = jnp.sum(p, axis=-1)
     acc = jnp.einsum("bkgt,btkd->bkgd", p,
                      v.reshape(b, pps * page, hkv, d))
-    return acc.reshape(b, h, d), m.reshape(b, h), l.reshape(b, h)
+    # same acc / max(l, 1e-30) convention as the Pallas kernel
+    o = acc / jnp.maximum(l, 1e-30)[..., None]
+    return o.reshape(b, h, d), m.reshape(b, h), l.reshape(b, h)
 
 
-def combine_partials(acc, m, l, axis_name: str):
-    """Exact cross-node merge of online-softmax partials: rebase every
-    node's accumulator to the global max and sum.  Nodes owning nothing
-    contribute (0, NEG_INF, 0) and vanish; a fully-masked (padding) slot
-    ends with l == 0 and yields 0, matching the Pallas kernel's
-    ``acc / max(l, 1e-30)`` convention."""
+def combine_partials(o, m, l, axis_name: str):
+    """Cross-node merge of ``(o, m, l)`` partials: weight every node's
+    normalized output by its share ``l * exp(m - m_glob)`` of the global
+    softmax mass, and sum.  Nodes owning nothing contribute (0, NEG_INF,
+    0) and vanish; a fully-masked (padding) slot has no mass anywhere
+    and yields 0.  When one node owns all of a sequence's pages (the
+    placed policy) its weight is exactly ``l / l == 1`` and every other
+    weight exactly 0, so the merge returns that node's output bit for
+    bit — pool serving then matches the single-node server exactly."""
     m_glob = lax.pmax(m, axis_name)
-    scale = jnp.exp(m - m_glob)
-    l_glob = lax.psum(l * scale, axis_name)
-    acc_glob = lax.psum(acc * scale[..., None], axis_name)
-    return acc_glob / jnp.maximum(l_glob, 1e-30)[..., None]
-
-
-def normalize_partials(acc, m, l):
-    """Single-node closure of the partial contract: with every page
-    owned locally, normalizing the accumulator *is* the full softmax
-    (same ``acc / max(l, 1e-30)`` convention as the Pallas kernel)."""
-    del m  # the local max cancels in acc / l
-    return acc / jnp.maximum(l, 1e-30)[..., None]
+    w = l * jnp.exp(m - m_glob)
+    w = w / jnp.maximum(lax.psum(w, axis_name), 1e-30)
+    return lax.psum(o.astype(jnp.float32) * w[..., None], axis_name)
 
 
 def make_serving_fns(model, mesh=None):
@@ -366,7 +362,12 @@ class PagedServer:
         self._prefill_state: Dict[int, np.ndarray] = {}
         self._prefill_unmatched: set = set()
         self.prefill_tokens_computed = 0
-        self._interpret = jax.default_backend() != "tpu"
+        self._interpret = ops.interpret_mode()
+        # interpret mode runs the partial-softmax attention in jnp: the
+        # Pallas emulation's per-call cost would otherwise dominate the
+        # very overhead the fused paths amortize.  On TPU every paged
+        # attention is the kernel.
+        self._jnp_attention = self._interpret
         # donating the page state lets XLA update the store in place;
         # CPU jit ignores donation (with a warning), so only opt in on
         # accelerators.
@@ -520,18 +521,33 @@ class PagedServer:
                                             mode="drop")
         return st
 
-    def _kernel_attention(self, q, st, page_table, lengths):
+    def _kernel_attention(self, q, st, page_table, lengths,
+                          return_stats: bool = False):
         """The Pallas paged-attention kernel over one layer's page
         state: the fp kernel for full-precision stores, the fused-
-        dequant ``paged_attention_q8`` for quantized ones (codes stream
-        HBM->VMEM, scales ride the scalar-prefetch page table, dequant
-        happens in-register — HBM traffic is the quantized bytes)."""
+        dequant ``paged_attention_q8`` for quantized ones (codes and
+        their scales stream HBM->VMEM, dequant happens in-register —
+        HBM traffic is the quantized bytes)."""
         if self.quantized:
             return _paged_q8_inner(q, st["k"], st["v"], st["ks"], st["vs"],
                                    page_table, lengths,
-                                   interpret=self._interpret)
+                                   interpret=self._interpret,
+                                   return_stats=return_stats)
         return _paged_inner(q, st["k"], st["v"], page_table, lengths,
-                            interpret=self._interpret)
+                            interpret=self._interpret,
+                            return_stats=return_stats)
+
+    def _attention_partial(self, q, st, table, owned, lengths):
+        """``(o, m, l)`` partials over the pages ``owned`` marks in
+        ``table`` — the contract of :func:`paged_attention_partial`,
+        computed by the Pallas kernel (not-owned entries become the
+        kernel's negative skip ids) or, in interpret mode, in jnp."""
+        if self._jnp_attention:
+            return paged_attention_partial(
+                q, st["k"], st["v"], table, owned, lengths,
+                k_scale=st.get("ks"), v_scale=st.get("vs"))
+        return self._kernel_attention(q, st, jnp.where(owned, table, -1),
+                                      lengths, return_stats=True)
 
     def decode_step(self, params, state, page_table, lengths, tokens):
         """One fused decode step for the whole active batch — the
@@ -563,22 +579,17 @@ class PagedServer:
     def _horizon_attention(self, q, st, page_table, lengths):
         """Per-step decode attention inside the fused horizon loop.
 
-        Uses the LSE-partial formulation — the same device contract the
-        pool hot path runs — normalized locally (exactly the full
-        softmax when every page is owned).  On TPU the Pallas
-        ``paged_attention`` kernel takes this seam per layer slice; in
-        CPU interpret mode the jnp partial path is the realistic fast
-        path (the Pallas emulation's per-call cost would otherwise
-        dominate the very overhead the horizon amortizes).  Both close
-        the same fused-dequant contract on quantized states.
-        q: [B, H, D] f32; returns [B, H, D]."""
-        if not self._interpret:
+        On TPU this is the Pallas ``paged_attention`` kernel per layer
+        slice; in CPU interpret mode it is the jnp partial form (see
+        ``_jnp_attention``), whose normalized output is exactly the full
+        softmax when every page is owned.  Both close the same
+        fused-dequant contract on quantized states.
+        q: [B, H, D]; returns [B, H, D]."""
+        if not self._jnp_attention:
             return self._kernel_attention(q, st, page_table, lengths)
-        owned = jnp.ones(page_table.shape, bool)
-        acc, m, l = paged_attention_partial(
-            q, st["k"], st["v"], page_table, owned, lengths,
-            k_scale=st.get("ks"), v_scale=st.get("vs"))
-        return normalize_partials(acc, m, l).astype(q.dtype)
+        o, _, _ = self._attention_partial(
+            q, st, page_table, jnp.ones(page_table.shape, bool), lengths)
+        return o.astype(q.dtype)
 
     def _fused_horizon_scan(self, params, state, page_table, lengths,
                             tokens, budget, eos_id, key=None,

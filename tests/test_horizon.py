@@ -141,6 +141,26 @@ def test_decode_horizon_matches_per_token(horizon):
     assert len(srv.table._pinned) == 0
 
 
+def test_decode_horizon_kernel_path_matches_per_token():
+    """The fused horizon with the Pallas kernel inside the scan — the
+    TPU's path, interpreted here (CPU runs default to the jnp partial
+    form) — emits exactly the per-token path's tokens."""
+    cfg, model, params = _tiny_model()
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, cfg.vocab_size, 7, dtype=np.int32)
+               for _ in range(2)]
+
+    def run(h):
+        srv = PagedServer(model, params, page_size=4, hbm_pages=32,
+                          dtype=jnp.float32)
+        srv._jnp_attention = False
+        for i, p in enumerate(prompts):
+            srv.add_request(i, p)
+        return srv.decode(6, horizon=h)
+
+    assert run(4) == run(None)
+
+
 def test_decode_horizon_under_eviction_pressure():
     """Window smaller than the total working set: horizon decode of one
     sequence spills the other to the flash tier and back, outputs

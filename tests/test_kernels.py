@@ -142,3 +142,40 @@ def test_paged_attention_q8(b, h, hkv, d, page, pps, npage):
                                atol=2e-5, rtol=2e-5)
     fp = ref.paged_attention_ref(q, kp_f, vp_f, pt, lens)
     assert float(jnp.abs(out - fp).max()) < 0.05   # quantization noise only
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_paged_attention_stats_match_partial_contract(quantized):
+    """``return_stats`` yields the ``(o, m, l)`` partial contract of
+    ``runtime.serve.paged_attention_partial`` (the pool's per-node
+    attention), and a negative page id is skipped as not owned."""
+    from repro.kernels.paged_attention import (paged_attention as pa,
+                                               paged_attention_q8 as pa8)
+    from repro.models.layers import quantize_kv
+    from repro.runtime.serve import paged_attention_partial
+    b, h, hkv, d, page, pps, npage = 3, 8, 2, 64, 16, 4, 16
+    ks = jax.random.split(KEY, 6)
+    q = jax.random.normal(ks[0], (b, h, d), jnp.float32)
+    kp = jax.random.normal(ks[1], (npage, page, hkv, d), jnp.float32)
+    vp = jax.random.normal(ks[2], (npage, page, hkv, d), jnp.float32)
+    pt = jax.random.permutation(ks[3], npage)[:b * pps].reshape(
+        b, pps).astype(jnp.int32)
+    lens = jnp.asarray([pps * page, 37, 20], jnp.int32)
+    # row 0 owns every page, row 1 a striped subset, row 2 none
+    owned = jnp.asarray([[True] * pps, [True, False, True, False],
+                         [False] * pps])
+    table = jnp.where(owned, pt, -1)
+    if quantized:
+        kq, ksc = quantize_kv(kp)
+        vq, vsc = quantize_kv(vp)
+        got = pa8(q, kq, vq, ksc, vsc, table, lens, interpret=True,
+                  return_stats=True)
+        want = paged_attention_partial(q, kq, vq, pt, owned, lens,
+                                       k_scale=ksc, v_scale=vsc)
+    else:
+        got = pa(q, kp, vp, table, lens, interpret=True, return_stats=True)
+        want = paged_attention_partial(q, kp, vp, pt, owned, lens)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=2e-5, rtol=2e-5)
+    assert float(jnp.abs(got[0][2]).max()) == 0.0    # nothing owned

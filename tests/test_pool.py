@@ -150,6 +150,28 @@ def test_pool_server_one_node_matches_paged(policy):
     assert all(agg[k] == per[0][k] for k in per[0])
 
 
+def test_pool_kernel_attention_matches_paged_server():
+    """On the kernel path — the TPU's, run interpreted here — a 1-node
+    pool reproduces PagedServer bit for bit: every page is owned by the
+    one node, so the ``(o, m, l)`` merge is an exact identity on the
+    kernel's own output (prefill logits, per-token and horizon tokens)."""
+    cfg, model, params = _tiny_model()
+    rng = np.random.default_rng(4)
+    B, S, gen = 2, 9, 6
+    prompts = rng.integers(0, cfg.vocab_size, (B, S), dtype=np.int32)
+    ref = PagedServer(model, params, page_size=4, hbm_pages=32,
+                      dtype=jnp.float32)
+    srv = PoolServer(model, params, n_nodes=1, page_size=4,
+                     hbm_pages_per_node=32, dtype=jnp.float32)
+    ref._jnp_attention = srv._jnp_attention = False
+    for i in range(B):
+        np.testing.assert_array_equal(
+            np.asarray(ref.add_request(i, prompts[i])),
+            np.asarray(srv.add_request(i, prompts[i])))
+    assert ref.decode(2) == srv.decode(2)
+    assert ref.decode(gen, horizon=4) == srv.decode(gen, horizon=4)
+
+
 def test_pool_router_frontend_control_plane():
     """End-to-end on one node: requests flow frontend -> Ether-oN frame
     -> placement -> sharded decode; place/free control frames are
