@@ -1347,37 +1347,6 @@ def isp_offload(out_path="BENCH_isp.json", quick=False):
           f"(target >=2x on pattern/rocksdb) -> {out_path}")
 
 
-# ---------------------------------------------------------------------------
-# roofline table from dry-run artifacts
-# ---------------------------------------------------------------------------
-
-
-def roofline_table(path="results/probe.jsonl"):
-    if not os.path.exists(path):
-        print(f"  (no {path}; run `python -m repro.launch.probe --all`)")
-        return
-    best = {}
-    with open(path) as f:
-        for line in f:
-            try:
-                r = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            if r.get("status") != "ok":
-                continue
-            best[(r["arch"], r["shape"], r["mesh"])] = r
-    _csv("roofline_table", 0.0, f"cells={len(best)}")
-    print(f"  {'arch':24s}{'shape':13s}{'mesh':7s}{'compute_ms':>11s}"
-          f"{'memory_ms':>10s}{'coll_ms':>9s}{'bottleneck':>11s}"
-          f"{'useful':>7s}{'roofline%':>10s}")
-    for (a, s, m), r in sorted(best.items()):
-        t = r["roofline"]
-        print(f"  {a:24s}{s:13s}{m:7s}{t['compute_s']*1e3:11.2f}"
-              f"{t['memory_s']*1e3:10.2f}{t['collective_s']*1e3:9.2f}"
-              f"{t['bottleneck']:>11s}{t['useful_flops_ratio']:7.2f}"
-              f"{t['roofline_fraction']*100:10.1f}")
-
-
 BENCHES = {
     "fig3": fig3_breakdown,
     "fig10": fig10_footprint,
@@ -1390,7 +1359,6 @@ BENCHES = {
     "serve": serve_decode,
     "pool": pool_serving,
     "isp": isp_offload,
-    "roofline": roofline_table,
 }
 
 

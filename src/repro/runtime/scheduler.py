@@ -33,6 +33,8 @@ from typing import Deque, Dict, List, Optional
 
 import numpy as np
 
+from repro.runtime import tracing
+
 
 @dataclasses.dataclass
 class Request:
@@ -202,28 +204,30 @@ class ContinuousBatcher:
         self.active[req.rid] = req
 
     def _admit(self):
-        if self.prefill_chunk is None:
-            while (self.waiting and len(self.active) < self.max_active and
-                   self._window_has_room(self.waiting[0])):
+        with tracing.span("scheduler.admit"):
+            if self.prefill_chunk is None:
+                while (self.waiting and len(self.active) < self.max_active
+                       and self._window_has_room(self.waiting[0])):
+                    req = self.waiting.popleft()
+                    self._activate(req, self._prefill(req))
+                return
+            # chunked admission: open admissions eagerly (prefix match
+            # only — zero compute), then run at most ONE jitted prefill
+            # chunk per scheduler iteration, so the decode horizon
+            # between iterations is never stalled by more than one
+            # chunk of admission work
+            while (self.waiting and
+                   len(self.active) + len(self.prefilling) < self.max_active
+                   and self._window_has_room(self.waiting[0])):
                 req = self.waiting.popleft()
-                self._activate(req, self._prefill(req))
-            return
-        # chunked admission: open admissions eagerly (prefix match only
-        # — zero compute), then run at most ONE jitted prefill chunk per
-        # scheduler iteration, so the decode horizon between iterations
-        # is never stalled by more than one chunk of admission work
-        while (self.waiting and
-               len(self.active) + len(self.prefilling) < self.max_active
-               and self._window_has_room(self.waiting[0])):
-            req = self.waiting.popleft()
-            self._begin_prefill(req)
-            self.prefilling[req.rid] = req
-        if self.prefilling:
-            rid, req = next(iter(self.prefilling.items()))
-            last = self.server.prefill_chunk(rid, self.prefill_chunk)
-            if last is not None:
-                del self.prefilling[rid]
-                self._activate(req, last)
+                self._begin_prefill(req)
+                self.prefilling[req.rid] = req
+            if self.prefilling:
+                rid, req = next(iter(self.prefilling.items()))
+                last = self.server.prefill_chunk(rid, self.prefill_chunk)
+                if last is not None:
+                    del self.prefilling[rid]
+                    self._activate(req, last)
 
     def _failover(self):
         """Failure-sync hook — PoolRouter overrides to requeue
@@ -254,27 +258,28 @@ class ContinuousBatcher:
         """One scheduler iteration: admit, decode the active set once
         (one token, or one fused horizon), retire finished sequences.
         Returns tokens produced."""
-        self._shed_expired()
-        self._admit()
-        # retire anything already done from its prefill token
-        self._retire()
-        # a node can die DURING admission/retirement (its control
-        # frames tick a fault injector's crash schedule): re-sync the
-        # active set before decoding, or the step would feed sequences
-        # the server just dropped
-        self._failover()
-        if not self.active:
-            return 0
-        if self.horizon <= 1:
-            out = self.server.decode(1, seqs=list(self.active),
-                                     sampling=self.sampling)
-            n = 0
-            for rid, toks in out.items():
-                self.active[rid].output.extend(toks)
-                n += len(toks)
-        else:
-            n = self._horizon_step()
-        self._retire()
+        with tracing.span("scheduler.iteration"):
+            self._shed_expired()
+            self._admit()
+            # retire anything already done from its prefill token
+            self._retire()
+            # a node can die DURING admission/retirement (its control
+            # frames tick a fault injector's crash schedule): re-sync the
+            # active set before decoding, or the step would feed
+            # sequences the server just dropped
+            self._failover()
+            if not self.active:
+                return 0
+            if self.horizon <= 1:
+                out = self.server.decode(1, seqs=list(self.active),
+                                         sampling=self.sampling)
+                n = 0
+                for rid, toks in out.items():
+                    self.active[rid].output.extend(toks)
+                    n += len(toks)
+            else:
+                n = self._horizon_step()
+            self._retire()
         return n
 
     def _horizon_step(self) -> int:
@@ -310,13 +315,15 @@ class ContinuousBatcher:
         return n
 
     def _retire(self):
-        for rid in [r for r, q in self.active.items() if q.done]:
-            req = self.active.pop(rid)
-            req.t_done = time.monotonic()
-            self.finished.append(req)
-            # every tier's pages come back in one call; the physical
-            # slots are reusable by the next waiting request immediately
-            self._release(rid)
+        with tracing.span("scheduler.retire"):
+            for rid in [r for r, q in self.active.items() if q.done]:
+                req = self.active.pop(rid)
+                req.t_done = time.monotonic()
+                self.finished.append(req)
+                # every tier's pages come back in one call; the physical
+                # slots are reusable by the next waiting request
+                # immediately
+                self._release(rid)
 
     def run_to_completion(self, max_iters: int = 10_000) -> dict:
         it = 0

@@ -70,7 +70,7 @@ from repro.kernels.paged_attention import (
     paged_attention as _paged_inner,
     paged_attention_q8 as _paged_q8_inner)
 from repro.models import layers as L
-from repro.runtime import sharding as shd
+from repro.runtime import sharding as shd, tracing
 
 NEG_INF = -1e30
 
@@ -982,57 +982,69 @@ class PagedServer:
         """
         prompt = self._prefill_state[seq_id]
         s = int(prompt.shape[0])
-        if seq_id in self._prefill_unmatched:
-            # lazy cached-prefix match (see begin_request): map shares,
-            # skip their prefill compute entirely
-            self._prefill_unmatched.discard(seq_id)
-            try:
-                self.table.match_prefix(seq_id, prompt)
-            except Exception:
-                self.free_sequence(seq_id)
-                raise
-        start = self.table.length(seq_id)
-        c = s - start if chunk is None else min(int(chunk), s - start)
-        try:
-            try:
-                rows = self.table.ensure_resident(seq_id, pin=True,
-                                                  n_tokens=start + c)
-                if start % self.page:
-                    # the chunk's first write lands mid-page: CoW-split
-                    # a shared prefix tail before the device touches it
-                    self.table.make_writable(seq_id, start // self.page)
-                    rows = self.table.row(seq_id, len(rows))
-            finally:
-                self.table.unpin_all()
-            row = np.zeros((_pow2(len(rows)),), np.int32)
-            row[:len(rows)] = rows
-            tokens = np.zeros((1, _pow2(c)), np.int32)
-            tokens[0, :c] = prompt[start:start + c]
-            logits, state = self._chunk_jit(
-                self.params, self.store.device_state(),
-                jnp.asarray(row), jnp.asarray(tokens),
-                jnp.asarray(start, jnp.int32), jnp.asarray(c, jnp.int32))
-        except Exception:
-            # rejected admissions must not leak window pages or leave a
-            # zero-length ghost in the live set; a failure inside the
-            # donated jit call additionally voids the store
-            self.free_sequence(seq_id)
-            self._recover_store()
-            raise
-        self.store.adopt(state)
-        self.table.set_length(seq_id, start + c)
-        self.prefill_tokens_computed += c
-        if start + c < s:
-            return None
-        # admission complete: index the prompt's pages for later sharers
-        del self._prefill_state[seq_id]
-        if self.prefix_cache:
-            self.table.register_prefix(seq_id, prompt)
-        self._pending[seq_id] = int(jnp.argmax(logits))
-        if seq_id in self._history:
-            # the pending token is the first generated one: it will be
-            # fed (and is thus drafter-visible) before it is re-emitted
-            self._history[seq_id].append(self._pending[seq_id])
+        with tracing.span("server.prefill") as counts:
+            with tracing.span("server.prefill.plan"):
+                try:
+                    if seq_id in self._prefill_unmatched:
+                        # lazy cached-prefix match (see begin_request):
+                        # map shares, skip their prefill compute entirely
+                        self._prefill_unmatched.discard(seq_id)
+                        self.table.match_prefix(seq_id, prompt)
+                    start = self.table.length(seq_id)
+                    c = s - start if chunk is None else \
+                        min(int(chunk), s - start)
+                    try:
+                        rows = self.table.ensure_resident(
+                            seq_id, pin=True, n_tokens=start + c)
+                        if start % self.page:
+                            # the chunk's first write lands mid-page:
+                            # CoW-split a shared prefix tail before the
+                            # device touches it
+                            self.table.make_writable(seq_id,
+                                                     start // self.page)
+                            rows = self.table.row(seq_id, len(rows))
+                    finally:
+                        self.table.unpin_all()
+                except Exception:
+                    # rejected admissions must not leak window pages or
+                    # leave a zero-length ghost in the live set
+                    self.free_sequence(seq_id)
+                    raise
+                row = np.zeros((_pow2(len(rows)),), np.int32)
+                row[:len(rows)] = rows
+                tokens = np.zeros((1, _pow2(c)), np.int32)
+                tokens[0, :c] = prompt[start:start + c]
+            counts.update(tokens=c, final=start + c == s)
+            with tracing.span("server.prefill.dispatch"):
+                try:
+                    logits, state = self._chunk_jit(
+                        self.params, self.store.device_state(),
+                        jnp.asarray(row), jnp.asarray(tokens),
+                        jnp.asarray(start, jnp.int32),
+                        jnp.asarray(c, jnp.int32))
+                except Exception:
+                    # a failure inside the donated jit call also voids
+                    # the store
+                    self.free_sequence(seq_id)
+                    self._recover_store()
+                    raise
+                self.store.adopt(state)
+            self.table.set_length(seq_id, start + c)
+            self.prefill_tokens_computed += c
+            if start + c < s:
+                return None
+            # admission complete: index the prompt's pages for later
+            # sharers
+            del self._prefill_state[seq_id]
+            if self.prefix_cache:
+                self.table.register_prefix(seq_id, prompt)
+            with tracing.span("server.prefill.wait"):
+                self._pending[seq_id] = int(jnp.argmax(logits))
+            if seq_id in self._history:
+                # the pending token is the first generated one: it will
+                # be fed (and is thus drafter-visible) before it is
+                # re-emitted
+                self._history[seq_id].append(self._pending[seq_id])
         return logits
 
     def add_request(self, seq_id: int, prompt: np.ndarray, *,
@@ -1163,7 +1175,9 @@ class PagedServer:
         every page the horizon can touch (``reserve_horizon``), then
         build the padded device inputs.  Shapes are bucketed to powers
         of two, so horizons over 3 and 4 active sequences share one
-        compiled program."""
+        compiled program.  Also returns the attention grid's counts:
+        ``bucket_rows`` x ``table_width`` page slots, of which ``pages``
+        hold (or will hold) KV."""
         try:
             rows = [self.table.reserve_horizon(s, budgets[s]) for s in seqs]
         except Exception:
@@ -1185,7 +1199,10 @@ class PagedServer:
         lens[:len(seqs)] = lengths
         buds = np.zeros((b2,), np.int32)
         buds[:len(seqs)] = [budgets[s] for s in seqs]
-        return jnp.asarray(table), jnp.asarray(lens), jnp.asarray(buds)
+        grid = {"bucket_rows": b2, "table_width": pps,
+                "pages": sum(len(r) for r in rows)}
+        return (jnp.asarray(table), jnp.asarray(lens), jnp.asarray(buds),
+                grid)
 
     @staticmethod
     def _stream_ids(seqs, b2: int):
@@ -1221,44 +1238,51 @@ class PagedServer:
         if _key is None:
             _key = jax.random.PRNGKey(sampling.seed)
         h_run = _pow2_floor(min(horizon, max(budgets[s] for s in seqs)))
-        page_table, lengths, buds = self._plan_horizon(
-            seqs, {s: min(budgets[s], h_run) for s in seqs})
-        try:
-            toks = np.zeros((lengths.shape[0],), np.int32)
-            toks[:len(seqs)] = [tokens[s] for s in seqs]
-            eos = np.int32(eos_id if eos_id is not None else -1)
-            emitted, _, state = self._horizon_jit(
-                self.params, self.store.device_state(),
-                page_table, lengths, jnp.asarray(toks), buds,
-                jnp.asarray(eos), _key,
-                jnp.float32(sampling.temperature),
-                jnp.float32(sampling.top_p),
-                self._stream_ids(seqs, lengths.shape[0]),
-                horizon=h_run)
-            # THE one transfer of the horizon: [h_run, B] int32 tokens
-            emitted = np.asarray(emitted)
-            self.store.adopt(state)
-            out = {}
-            for i, s in enumerate(seqs):
-                got = [int(t) for t in emitted[:, i] if t >= 0]
-                out[s] = got
-                if s in self._history:
-                    self._history[s].extend(got)
-                # committed appends == emitted tokens (each fused step
-                # feeds one token and emits one); rollback the unused
-                # tail of the reservation
-                self.table.commit_horizon(s, len(got))
-        except Exception:
-            self._recover_store()
-            # store intact (the failure was not a donated-buffer loss):
-            # roll back every surviving sequence's unused reservation so
-            # no data-less pages stay resident
-            for s in seqs:
-                if s in self._seqs:
-                    self.table.commit_horizon(s, 0)
-            raise
-        finally:
-            self.table.unpin_all()
+        with tracing.span("server.horizon") as counts:
+            with tracing.span("server.horizon.plan"):
+                page_table, lengths, buds, grid = self._plan_horizon(
+                    seqs, {s: min(budgets[s], h_run) for s in seqs})
+            counts.update(grid)
+            try:
+                with tracing.span("server.horizon.dispatch"):
+                    toks = np.zeros((lengths.shape[0],), np.int32)
+                    toks[:len(seqs)] = [tokens[s] for s in seqs]
+                    eos = np.int32(eos_id if eos_id is not None else -1)
+                    emitted, _, state = self._horizon_jit(
+                        self.params, self.store.device_state(),
+                        page_table, lengths, jnp.asarray(toks), buds,
+                        jnp.asarray(eos), _key,
+                        jnp.float32(sampling.temperature),
+                        jnp.float32(sampling.top_p),
+                        self._stream_ids(seqs, lengths.shape[0]),
+                        horizon=h_run)
+                with tracing.span("server.horizon.wait"):
+                    # THE one transfer of the horizon: [h_run, B] int32
+                    # tokens
+                    emitted = np.asarray(emitted)
+                with tracing.span("server.horizon.commit"):
+                    self.store.adopt(state)
+                    out = {}
+                    for i, s in enumerate(seqs):
+                        got = [int(t) for t in emitted[:, i] if t >= 0]
+                        out[s] = got
+                        if s in self._history:
+                            self._history[s].extend(got)
+                        # committed appends == emitted tokens (each fused
+                        # step feeds one token and emits one); rollback
+                        # the unused tail of the reservation
+                        self.table.commit_horizon(s, len(got))
+            except Exception:
+                self._recover_store()
+                # store intact (the failure was not a donated-buffer
+                # loss): roll back every surviving sequence's unused
+                # reservation so no data-less pages stay resident
+                for s in seqs:
+                    if s in self._seqs:
+                        self.table.commit_horizon(s, 0)
+                raise
+            finally:
+                self.table.unpin_all()
         return out
 
     # -- one committed speculative pass ---------------------------------------
@@ -1323,7 +1347,7 @@ class PagedServer:
             return self.horizon_batch(tokens, budgets, horizon,
                                       eos_id=eos_id, sampling=sampling,
                                       _key=_key)
-        page_table, lengths, buds = self._plan_horizon(
+        page_table, lengths, buds, _ = self._plan_horizon(
             seqs, {s: min(budgets[s], h_run) for s in seqs})
         b2 = int(lengths.shape[0])
         w = self.spec_lookup_window
