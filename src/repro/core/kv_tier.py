@@ -14,7 +14,9 @@ The cache is split along the host/device boundary:
     arrays ``[n_layers, hbm_pages, page, n_kv_heads, head_dim]`` holds
     every layer's pages, so a physical page id addresses the KV of all
     layers at once and one transfer moves a whole stacked page.  The
-    jitted serving step consumes/produces these arrays directly.
+    jitted serving steps take these arrays, carry them whole through
+    their layer loop, append in place and return them; the paged
+    kernel reads them at a layer index.
   * :class:`PageTableManager` — host-side policy.  Owns the logical
     (seq_id, page_idx) -> physical mapping, LRU eviction into the host
     tier, pinning, prefetch, per-tier stats, sequence lifetime
@@ -112,10 +114,13 @@ class PageStore:
 
     ``k_pages``/``v_pages``: [n_layers, hbm_pages, page, n_kv_heads,
     head_dim].  Layer ``li`` of physical page ``p`` is
-    ``k_pages[li, p]`` — the per-layer slice a ``lax.scan`` over layers
-    feeds to the Pallas paged_attention kernel.  All mutation from the
-    serving hot path happens *inside* jit (batched scatters); the
-    manager only moves whole stacked pages across the HBM/host boundary.
+    ``k_pages[li, p]``.  The jitted steps carry the arrays whole through
+    their ``lax.scan`` over layers: the append scatters at ``[li, page,
+    slot]`` in place and the Pallas paged_attention kernel reads layer
+    ``li`` of the pages its table names, so no step slices or restacks
+    a layer of the store.  All mutation from the serving hot path
+    happens *inside* jit (batched scatters); the manager only moves
+    whole stacked pages across the HBM/host boundary.
 
     **Quantized page format** (``page_dtype`` in {"int8", "fp8"}): the
     page arrays hold codes and a parallel per-slot, per-head scale
@@ -232,8 +237,8 @@ class PageStore:
     def device_state(self) -> Dict[str, jnp.ndarray]:
         """The store as the pytree the jitted serving steps consume and
         return: {"k", "v"} plus {"ks", "vs"} when quantized.  Every
-        leaf's leading axis is layers, so a ``lax.scan`` over layers
-        slices the whole state at once."""
+        leaf's leading axis is layers; a step carries the whole state
+        through its layer loop and reads it at the layer index."""
         st = {"k": self.k_pages, "v": self.v_pages}
         if self.quantized:
             st["ks"] = self.k_scale
@@ -309,7 +314,8 @@ class PageStore:
 
     def layer_state(self, li: int) -> Dict[str, jnp.ndarray]:
         """Per-layer slice of :meth:`device_state` (eager reference
-        paths; the jitted path slices via ``lax.scan``)."""
+        paths; the jitted steps read the whole state at a layer
+        index)."""
         st = {"k": self.k_pages[li], "v": self.v_pages[li]}
         if self.quantized:
             st["ks"] = self.k_scale[li]

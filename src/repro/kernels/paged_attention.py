@@ -25,12 +25,16 @@ partial form pool nodes merge across the mesh
 
 Calling convention: the batched serving path holds *stacked* pages
 ``[n_layers, hbm_pages, page, Hkv, D]`` (core.kv_tier.PageStore) and
-calls this kernel once per layer from inside a jitted ``lax.scan`` over
-layers — each scan step feeds the layer's ``[hbm_pages, page, Hkv, D]``
-slice plus the (shared) page-table row block.  The kernel itself is
-layer-agnostic; ``paged_attention`` below is safe to trace inside an
-enclosing jit (runtime/serve.py fuses append-scatter + attention + FFN
-into one step).
+carries them whole through its jitted ``lax.scan`` over layers.  Each
+layer step passes the whole stacked array plus its layer index, a third
+scalar-prefetch operand: the page index map returns ``(layer,
+page_table[b, pi], 0, 0, 0)``, so one grid step still DMAs one page of
+one layer and no per-layer slice of the store is ever materialized.
+The 4-D form ``paged_attention(q, k_pages, v_pages, table, lengths)``
+is the one-layer case of the same call (a leading-axis reshape and
+layer 0).  ``paged_attention`` is safe to trace inside an enclosing
+jit (runtime/serve.py fuses append-scatter + attention + FFN into one
+step).
 """
 from __future__ import annotations
 
@@ -45,8 +49,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *refs, page: int,
-                  n_pages_per_seq: int, sm_scale: float, hkv: int,
+def _paged_kernel(pt_ref, len_ref, li_ref, q_ref, k_ref, v_ref, *refs,
+                  page: int, n_pages_per_seq: int, sm_scale: float, hkv: int,
                   quantized: bool, stats: bool):
     refs = list(refs)
     ks_ref, vs_ref = (refs.pop(0), refs.pop(0)) if quantized else (None,
@@ -99,10 +103,17 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *refs, page: int,
             lo_ref[0] = l_ref[...]
 
 
-def _paged_call(q, k_pages, v_pages, scales, page_table, lengths, *,
+def _paged_call(q, k_pages, v_pages, scales, page_table, lengths, layer, *,
                 interpret: bool, return_stats: bool, name: str):
+    """The one kernel call.  Pages are either stacked ``[L, P, page,
+    Hkv, D]`` (scales ``[L, P, page, Hkv]``) read at ``layer``, or one
+    layer's ``[P, page, Hkv, D]`` when ``layer`` is None."""
+    if layer is None:
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        scales = tuple(s[None] for s in scales)
+        layer = 0
     b, h, d = q.shape
-    _, page, hkv, _ = k_pages.shape
+    _, _, page, hkv, _ = k_pages.shape
     pps = page_table.shape[1]
     g = h // hkv
     kernel = functools.partial(
@@ -111,17 +122,18 @@ def _paged_call(q, k_pages, v_pages, scales, page_table, lengths, *,
         stats=return_stats)
 
     def page_spec(*tail):
-        # physical page id comes from the prefetched page table (a
+        # one page of one layer: the layer comes from the prefetched
+        # index, the physical page id from the prefetched page table (a
         # skipped, not-owned page still names a valid block); the block
         # spans every KV head of the page
         return pl.BlockSpec(
-            (1, page) + tail,
-            lambda bb, pi, pt, ln: (jnp.maximum(pt[bb, pi], 0), 0) +
-            (0,) * len(tail))
+            (pl.squeezed, 1, page) + tail,
+            lambda bb, pi, pt, ln, li: (li[0], jnp.maximum(pt[bb, pi], 0),
+                                        0) + (0,) * len(tail))
 
     def head_spec(last):
         return pl.BlockSpec((1, hkv, g, last),
-                            lambda bb, pi, pt, ln: (bb, 0, 0, 0))
+                            lambda bb, pi, pt, ln, li: (bb, 0, 0, 0))
 
     out_specs = [head_spec(d)]
     out_shape = [jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype)]
@@ -129,7 +141,7 @@ def _paged_call(q, k_pages, v_pages, scales, page_table, lengths, *,
         out_specs += [head_spec(1), head_spec(1)]
         out_shape += [jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, pps),
         in_specs=[head_spec(d), page_spec(hkv, d), page_spec(hkv, d)] +
                  [page_spec(hkv) for _ in scales],
@@ -148,7 +160,8 @@ def _paged_call(q, k_pages, v_pages, scales, page_table, lengths, *,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(page_table, lengths, q.reshape(b, hkv, g, d), k_pages, v_pages,
+    )(page_table, lengths, jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q.reshape(b, hkv, g, d), k_pages, v_pages,
       *[s.astype(jnp.float32) for s in scales])
     if not return_stats:
         return out[0].reshape(b, h, d)
@@ -157,25 +170,29 @@ def _paged_call(q, k_pages, v_pages, scales, page_table, lengths, *,
 
 
 def paged_attention_q8(q, k_pages, v_pages, k_scale, v_scale, page_table,
-                       lengths, *, interpret: bool = False,
+                       lengths, *, layer=None, interpret: bool = False,
                        return_stats: bool = False):
     """Quantized-KV paged decode attention (int8 or fp8 codes).
 
-    q: [B, H, D] float; k_pages/v_pages: codes [n_pages, page, Hkv, D];
-    k_scale/v_scale: f32 [n_pages, page, Hkv]; page_table: [B, pps] int32
-    (negative = not owned, skipped); lengths: [B].  Returns [B, H, D],
-    or (o, m [B, H], l [B, H]) with ``return_stats``."""
+    q: [B, H, D] float; k_pages/v_pages: codes [n_pages, page, Hkv, D],
+    or stacked [n_layers, n_pages, page, Hkv, D] read at ``layer`` (an
+    int32 scalar, traced or not); k_scale/v_scale: f32 [n_pages, page,
+    Hkv] (stacked: [n_layers, n_pages, page, Hkv]); page_table: [B, pps]
+    int32 (negative = not owned, skipped); lengths: [B].  Returns
+    [B, H, D], or (o, m [B, H], l [B, H]) with ``return_stats``."""
     return _paged_call(q, k_pages, v_pages, (k_scale, v_scale), page_table,
-                       lengths, interpret=interpret,
+                       lengths, layer, interpret=interpret,
                        return_stats=return_stats, name="paged_attention_q8")
 
 
 def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
-                    interpret: bool = False, return_stats: bool = False):
-    """q: [B, H, D]; k_pages/v_pages: [n_pages, page, Hkv, D];
-    page_table: [B, pages_per_seq] int32 (negative = not owned, skipped);
-    lengths: [B] int32.  Returns [B, H, D], or (o, m [B, H], l [B, H])
-    with ``return_stats``."""
-    return _paged_call(q, k_pages, v_pages, (), page_table, lengths,
+                    layer=None, interpret: bool = False,
+                    return_stats: bool = False):
+    """q: [B, H, D]; k_pages/v_pages: [n_pages, page, Hkv, D], or stacked
+    [n_layers, n_pages, page, Hkv, D] read at ``layer`` (an int32
+    scalar, traced or not); page_table: [B, pages_per_seq] int32
+    (negative = not owned, skipped); lengths: [B] int32.  Returns
+    [B, H, D], or (o, m [B, H], l [B, H]) with ``return_stats``."""
+    return _paged_call(q, k_pages, v_pages, (), page_table, lengths, layer,
                        interpret=interpret, return_stats=return_stats,
                        name="paged_attention")

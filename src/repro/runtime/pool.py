@@ -391,11 +391,11 @@ class PoolServer(PagedServer):
             owned = valid & (local_new >= 0) & (local_new < n_local)
             return jnp.where(owned, local_new, n_local)
 
-        def attention(q, st, new_lengths):
+        def attention(q, st, li, new_lengths):
             # quantized stores dequantize in the partial itself (the
             # same multiply on every node), so the LSE merge stays
             # device-invariant across pool shards
-            o, m, l = self._attention_partial(q, st, local_table,
+            o, m, l = self._attention_partial(q, st, li, local_table,
                                               col_owned, new_lengths)
             return combine_partials(o, m, l, POOL_AXIS).astype(self.dtype)
 
@@ -528,8 +528,8 @@ class PoolServer(PagedServer):
         return self._prefill_chunk_scan(
             params, state, page_row, tokens, start, n_valid,
             append_target=append_target,
-            attention=lambda q, st, table, lengths:
-                attention(q, st, lengths))
+            attention=lambda q, st, li, table, lengths:
+                attention(q, st, li, lengths))
 
     def step_reference(self, tokens):
         raise NotImplementedError(

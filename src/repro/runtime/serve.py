@@ -11,10 +11,12 @@ Two paths:
     pinning, prefetch, admission accounting) over a device-resident
     **PageStore** with *stacked* per-layer pages, consumed by the Pallas
     ``paged_attention`` kernel.  One jitted ``decode_step`` advances
-    every layer and every active sequence per token: a single batched
-    scatter appends the new K/V for all layers/sequences, then a
-    ``lax.scan`` over layers runs the paged-attention kernel against
-    each layer's page slice.  Prefill is **chunked**: each jitted
+    every layer and every active sequence per token through one
+    ``lax.scan`` over layers that carries the store whole: each layer
+    appends the new K/V of every sequence in place at its layer index
+    and the kernel reads that layer of the pages the table names, so no
+    step program slices or restacks a layer of the store.  Prefill is
+    **chunked**: each jitted
     ``prefill_chunk_step`` writes one pow2-bucketed chunk of prompt
     pages and attends over the paged context, and prompts whose prefix
     is already resident skip the covered pages entirely (the
@@ -76,7 +78,8 @@ NEG_INF = -1e30
 
 
 def paged_attention_partial(q, k_pages, v_pages, local_table, col_owned,
-                            lengths, k_scale=None, v_scale=None):
+                            lengths, k_scale=None, v_scale=None, *,
+                            layer=None):
     """Paged decode attention returning online-softmax partials.
 
     The device contract of distributed paged attention (the pool hot
@@ -86,29 +89,33 @@ def paged_attention_partial(q, k_pages, v_pages, local_table, col_owned,
     merge nodes exactly (``combine_partials``); on one node owning
     every page, ``o`` *is* the full attention.  This is the jnp form
     (CPU interpret mode); on TPU the Pallas ``paged_attention`` kernel
-    computes the same contract per layer slice (``return_stats``).
+    computes the same contract (``return_stats``).
 
-    q: [B, H, D]; k_pages/v_pages: *local* [P_node, page, Hkv, D];
+    q: [B, H, D]; k_pages/v_pages: *local* [P_node, page, Hkv, D], or
+    the stacked [L, P_node, page, Hkv, D] read at ``layer`` (gathering
+    only the pages the table names, never the layer's whole slice);
     local_table: [B, pps] local physical ids (garbage where not owned);
     col_owned: [B, pps] bool — does this node own that logical page;
     lengths: [B] post-append sequence lengths.
-    ``k_scale``/``v_scale`` ([P_node, page, Hkv] f32, quantized stores
-    only) dequantize in-register with the exact same multiply on every
-    node, so the LSE merge stays device-invariant across pool shards.
+    ``k_scale``/``v_scale`` ([P_node, page, Hkv] f32, stacked like the
+    pages; quantized stores only) dequantize in-register with the exact
+    same multiply on every node, so the LSE merge stays device-invariant
+    across pool shards.
     Returns (o [B, H, D] f32, m [B, H] f32, l [B, H] f32).
     """
     b, h, d = q.shape
-    _, page, hkv, _ = k_pages.shape
+    page, hkv = k_pages.shape[-3:-1]
     pps = local_table.shape[1]
     g = h // hkv
     sm_scale = 1.0 / math.sqrt(d)
 
     safe = jnp.where(col_owned, local_table, 0)
-    k = k_pages[safe].astype(jnp.float32)        # [B, pps, page, Hkv, D]
-    v = v_pages[safe].astype(jnp.float32)
+    at = safe if layer is None else (layer, safe)
+    k = k_pages[at].astype(jnp.float32)          # [B, pps, page, Hkv, D]
+    v = v_pages[at].astype(jnp.float32)
     if k_scale is not None:
-        k = k * k_scale[safe][..., None]         # fused dequant, no fp32
-        v = v * v_scale[safe][..., None]         # page materialization
+        k = k * k_scale[at][..., None]           # fused dequant, no fp32
+        v = v * v_scale[at][..., None]           # page materialization
     qg = q.reshape(b, hkv, g, d).astype(jnp.float32)
     s = jnp.einsum("bkgd,bptkd->bkgpt", qg, k) * sm_scale
     pos = (jnp.arange(pps, dtype=jnp.int32)[:, None] * page +
@@ -497,57 +504,87 @@ class PagedServer:
 
     # -- jitted device programs ----------------------------------------------
 
-    def _append_state(self, st, tgt, offs, k_new, v_new):
-        """Scatter one new KV position per row into a per-layer page
-        state dict (``tgt`` rows at the out-of-bounds sentinel are
-        dropped).  Quantized stores quantize **on device at write
-        time**: codes and their per-slot scales land in one step, so
-        the page arrays never hold full-precision data.
-        k_new/v_new: [N, Hkv, D]; tgt/offs: [N]."""
-        st = dict(st)
+    def _append_state(self, st, li, tgt, offs, k_new, v_new):
+        """Scatter one new KV position per row into layer ``li`` of the
+        stacked page state, in place: ``[li, tgt, offs]`` of every leaf
+        (``tgt`` rows at the out-of-bounds sentinel are dropped).
+        Quantized stores quantize **on device at write time**: codes
+        and their per-slot scales land in one step, so the page arrays
+        never hold full-precision data.
+        k_new/v_new: [N, Hkv, D]; tgt/offs: [N]; li: [] int32."""
+        new = {"k": k_new, "v": v_new}
         if self.quantized:
-            kq, ks = quantize_page_kv(k_new, self.store.qmax,
-                                      self.store.code_dtype)
-            vq, vs = quantize_page_kv(v_new, self.store.qmax,
-                                      self.store.code_dtype)
-            st["k"] = st["k"].at[tgt, offs].set(kq, mode="drop")
-            st["v"] = st["v"].at[tgt, offs].set(vq, mode="drop")
-            st["ks"] = st["ks"].at[tgt, offs].set(ks, mode="drop")
-            st["vs"] = st["vs"].at[tgt, offs].set(vs, mode="drop")
-            return st
-        st["k"] = st["k"].at[tgt, offs].set(k_new.astype(st["k"].dtype),
-                                            mode="drop")
-        st["v"] = st["v"].at[tgt, offs].set(v_new.astype(st["v"].dtype),
-                                            mode="drop")
-        return st
+            new["k"], new["ks"] = quantize_page_kv(
+                k_new, self.store.qmax, self.store.code_dtype)
+            new["v"], new["vs"] = quantize_page_kv(
+                v_new, self.store.qmax, self.store.code_dtype)
+        return {n: a.at[li, tgt, offs].set(new[n].astype(a.dtype),
+                                           mode="drop")
+                for n, a in st.items()}
 
-    def _kernel_attention(self, q, st, page_table, lengths,
+    def _kernel_attention(self, q, st, li, page_table, lengths,
                           return_stats: bool = False):
-        """The Pallas paged-attention kernel over one layer's page
-        state: the fp kernel for full-precision stores, the fused-
-        dequant ``paged_attention_q8`` for quantized ones (codes and
-        their scales stream HBM->VMEM, dequant happens in-register —
-        HBM traffic is the quantized bytes)."""
+        """The Pallas paged-attention kernel over layer ``li`` of the
+        stacked page state: the fp kernel for full-precision stores,
+        the fused-dequant ``paged_attention_q8`` for quantized ones
+        (codes and their scales stream HBM->VMEM, dequant happens
+        in-register — HBM traffic is the quantized bytes)."""
         if self.quantized:
             return _paged_q8_inner(q, st["k"], st["v"], st["ks"], st["vs"],
-                                   page_table, lengths,
+                                   page_table, lengths, layer=li,
                                    interpret=self._interpret,
                                    return_stats=return_stats)
         return _paged_inner(q, st["k"], st["v"], page_table, lengths,
-                            interpret=self._interpret,
+                            layer=li, interpret=self._interpret,
                             return_stats=return_stats)
 
-    def _attention_partial(self, q, st, table, owned, lengths):
-        """``(o, m, l)`` partials over the pages ``owned`` marks in
-        ``table`` — the contract of :func:`paged_attention_partial`,
-        computed by the Pallas kernel (not-owned entries become the
-        kernel's negative skip ids) or, in interpret mode, in jnp."""
+    def _attention_partial(self, q, st, li, table, owned, lengths):
+        """``(o, m, l)`` partials of layer ``li`` over the pages
+        ``owned`` marks in ``table`` — the contract of
+        :func:`paged_attention_partial`, computed by the Pallas kernel
+        (not-owned entries become the kernel's negative skip ids) or, in
+        interpret mode, in jnp."""
         if self._jnp_attention:
             return paged_attention_partial(
                 q, st["k"], st["v"], table, owned, lengths,
-                k_scale=st.get("ks"), v_scale=st.get("vs"))
-        return self._kernel_attention(q, st, jnp.where(owned, table, -1),
+                k_scale=st.get("ks"), v_scale=st.get("vs"), layer=li)
+        return self._kernel_attention(q, st, li,
+                                      jnp.where(owned, table, -1),
                                       lengths, return_stats=True)
+
+    def _layer_stack(self, params, h, state, positions, tgt, offs,
+                     attention):
+        """The layer loop every step program runs: one ``lax.scan`` with
+        carry ``(h, state)`` over ``(params["layers"], layer index)``.
+        The stacked page state is carried whole — never a scan input or
+        output, so no layer's pages are sliced out, restacked or
+        copied.  Each layer appends the new K/V at ``[li, tgt, offs]``
+        in place and ``attention(q, state, li) -> [N, H, D]`` reads
+        layer ``li`` of the pages its table names.
+
+        h: [B, S, d] for the S new positions of each of B rows
+        (``positions`` [B, S] or broadcastable); tgt/offs: [B*S] append
+        targets.  Returns (h, state)."""
+        cfg = self.cfg
+        b, s = h.shape[:2]
+        n = b * s
+
+        def body(carry, xs):
+            hh, st = carry
+            lp, li = xs
+            q, k, v = self._attn_inputs(lp, hh, positions)
+            st = self._append_state(st, li, tgt, offs,
+                                    k.reshape(n, cfg.n_kv_heads, cfg.hd),
+                                    v.reshape(n, cfg.n_kv_heads, cfg.hd))
+            o = attention(q.reshape(n, cfg.n_heads, cfg.hd)
+                          .astype(self.dtype), st, li)
+            return (self._attn_out_ffn(lp, hh, o.reshape(b, s, -1)),
+                    st), None
+
+        (h, state), _ = lax.scan(
+            body, (h, state),
+            (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+        return h, state
 
     def decode_step(self, params, state, page_table, lengths, tokens):
         """One fused decode step for the whole active batch — the
@@ -570,25 +607,26 @@ class PagedServer:
             # out-of-bounds sentinel => scatter drops padding slots
             append_target=lambda phys, valid:
                 jnp.where(valid, phys, n_phys),
-            attention=lambda q, st, new_lengths:
-                self._kernel_attention(q, st, page_table, new_lengths))
+            attention=lambda q, st, li, new_lengths:
+                self._kernel_attention(q, st, li, page_table, new_lengths))
         return logits, state
 
     # -- fused decode horizon -------------------------------------------------
 
-    def _horizon_attention(self, q, st, page_table, lengths):
+    def _horizon_attention(self, q, st, li, page_table, lengths):
         """Per-step decode attention inside the fused horizon loop.
 
-        On TPU this is the Pallas ``paged_attention`` kernel per layer
-        slice; in CPU interpret mode it is the jnp partial form (see
+        On TPU this is the Pallas ``paged_attention`` kernel at layer
+        ``li``; in CPU interpret mode it is the jnp partial form (see
         ``_jnp_attention``), whose normalized output is exactly the full
         softmax when every page is owned.  Both close the same
         fused-dequant contract on quantized states.
         q: [B, H, D]; returns [B, H, D]."""
         if not self._jnp_attention:
-            return self._kernel_attention(q, st, page_table, lengths)
+            return self._kernel_attention(q, st, li, page_table, lengths)
         o, _, _ = self._attention_partial(
-            q, st, page_table, jnp.ones(page_table.shape, bool), lengths)
+            q, st, li, page_table, jnp.ones(page_table.shape, bool),
+            lengths)
         return o.astype(q.dtype)
 
     def _fused_horizon_scan(self, params, state, page_table, lengths,
@@ -605,9 +643,9 @@ class PagedServer:
 
         ``append_target(phys, valid) -> [B]`` maps each sequence's tail
         physical page to the scatter row (out-of-bounds sentinel drops
-        finished/padding/non-owned appends); ``attention(q, st,
+        finished/padding/non-owned appends); ``attention(q, st, li,
         new_lengths) -> [B, H, D]`` closes the paged-attention contract
-        over the per-layer state slice (locally normalized, or
+        over layer ``li`` of the stacked state (locally normalized, or
         ownership-masked + pool-merged).
 
         Returns (emitted [H, B], last step's logits [B, V] f32, state)
@@ -628,7 +666,6 @@ class PagedServer:
         and greedy outputs stay bit-identical to the key-free program.
         """
         cfg = self.cfg
-        b = tokens.shape[0]
 
         def step(carry, i):
             state, lengths, tokens, budget = carry
@@ -642,18 +679,9 @@ class PagedServer:
             new_lengths = lengths + valid.astype(jnp.int32)
 
             h = L.embed_tokens(params["embed"], tokens[:, None], self.dtype)
-
-            def body(hh, xs):
-                # the scan slices every state leaf's leading layer axis,
-                # so st is this layer's {"k","v"[,"ks","vs"]} pages
-                lp, st = xs
-                q, k, v = self._attn_inputs(lp, hh, pos)
-                st = self._append_state(st, tgt, offs, k[:, 0], v[:, 0])
-                o = attention(q[:, 0].astype(self.dtype), st, new_lengths)
-                return (self._attn_out_ffn(lp, hh, o.reshape(b, 1, -1)),
-                        st)
-
-            h, state = lax.scan(body, h, (params["layers"], state))
+            h, state = self._layer_stack(
+                params, h, state, pos, tgt, offs,
+                lambda q, st, li: attention(q, st, li, new_lengths))
             h = L.apply_norm(params["final_norm"], h, cfg.norm)
             logits = L.unembed(params["embed"], params.get("lm_head"), h,
                                cfg.tie_embeddings)[:, 0]
@@ -726,8 +754,9 @@ class PagedServer:
             # out-of-bounds sentinel => scatter drops finished/padding
             append_target=lambda phys, valid:
                 jnp.where(valid, phys, n_phys),
-            attention=lambda q, st, new_lengths:
-                self._horizon_attention(q, st, page_table, new_lengths))
+            attention=lambda q, st, li, new_lengths:
+                self._horizon_attention(q, st, li, page_table,
+                                        new_lengths))
 
     # -- speculative decoding (draft-verify on the horizon scaffold) ----------
 
@@ -746,10 +775,11 @@ class PagedServer:
         sequence from the device-resident history table; the fed block
         ``[pending, d_1 .. d_{H-1}]`` runs the layer stack as ``horizon``
         decode-shaped queries with per-position causal lengths (one
-        ``lax.scan`` over layers — the H-position forward costs one
-        model pass, which is the entire speedup); position ``j``'s
-        logits then judge candidate ``d_{j+1}``.  Acceptance on device:
-        greedy mode accepts while ``argmax == candidate``; sampling
+        pass of the layer loop, :meth:`_layer_stack` — the H-position
+        forward costs one model pass, which is the entire speedup);
+        position ``j``'s logits then judge candidate ``d_{j+1}``.
+        Acceptance on device: greedy mode accepts while
+        ``argmax == candidate``; sampling
         mode uses *Gumbel coupling* — pre-draw the target token from
         the same per-(stream, position) key the plain fused horizon
         folds, accept a candidate iff it equals that target, and emit
@@ -771,7 +801,6 @@ class PagedServer:
         b = tokens.shape[0]
         pps = page_table.shape[1]
         hzn = horizon
-        hkv, hd, nh = cfg.n_kv_heads, cfg.hd, cfg.n_heads
 
         draft = draft_ngram(hist, hist_len, hzn - 1)          # [B, H-1]
         n_drafted = jnp.sum((draft >= 0).astype(jnp.int32), axis=1)
@@ -790,18 +819,9 @@ class PagedServer:
         row_lengths = jnp.where(append_ok, pos + 1, 0).reshape(-1)
 
         h = L.embed_tokens(params["embed"], fed, self.dtype)
-
-        def body(hh, xs):
-            lp, st = xs
-            q, k, v = self._attn_inputs(lp, hh, pos)
-            st = self._append_state(st, tgt, offs,
-                                    k.reshape(b * hzn, hkv, hd),
-                                    v.reshape(b * hzn, hkv, hd))
-            o = attention(q.reshape(b * hzn, nh, hd).astype(self.dtype),
-                          st, row_lengths)
-            return self._attn_out_ffn(lp, hh, o.reshape(b, hzn, -1)), st
-
-        h, state = lax.scan(body, h, (params["layers"], state))
+        h, state = self._layer_stack(
+            params, h, state, pos, tgt, offs,
+            lambda q, st, li: attention(q, st, li, row_lengths))
         h = L.apply_norm(params["final_norm"], h, cfg.norm)
         logits = L.unembed(params["embed"], params.get("lm_head"), h,
                            cfg.tie_embeddings).astype(jnp.float32)
@@ -872,8 +892,9 @@ class PagedServer:
             horizon=horizon,
             append_target=lambda phys, valid:
                 jnp.where(valid, phys, n_phys),
-            attention=lambda q, st, row_lengths:
-                self._horizon_attention(q, st, rows_table, row_lengths))
+            attention=lambda q, st, li, row_lengths:
+                self._horizon_attention(q, st, li, rows_table,
+                                        row_lengths))
 
     def _prefill_chunk_scan(self, params, state, page_row, tokens, start,
                             n_valid, *, append_target, attention):
@@ -887,9 +908,9 @@ class PagedServer:
 
         ``append_target(phys, valid) -> [C]`` maps each position's
         destination page to the scatter row (sentinel drops padding /
-        non-owned writes); ``attention(q, st, table, lengths) ->
-        [C, H, D]`` closes the paged-attention contract over the
-        per-layer state slice.
+        non-owned writes); ``attention(q, st, li, table, lengths) ->
+        [C, H, D]`` closes the paged-attention contract over layer
+        ``li`` of the stacked state.
         """
         cfg = self.cfg
         c = tokens.shape[1]
@@ -906,15 +927,9 @@ class PagedServer:
         table = jnp.broadcast_to(page_row[None, :], (c, pps))
 
         h = L.embed_tokens(params["embed"], tokens, self.dtype)
-
-        def body(hh, xs):
-            lp, st = xs
-            q, k, v = self._attn_inputs(lp, hh, positions)
-            st = self._append_state(st, phys_w, offs, k[0], v[0])
-            o = attention(q[0].astype(self.dtype), st, table, lengths_q)
-            return self._attn_out_ffn(lp, hh, o.reshape(1, c, -1)), st
-
-        h, state = lax.scan(body, h, (params["layers"], state))
+        h, state = self._layer_stack(
+            params, h, state, positions, phys_w, offs,
+            lambda q, st, li: attention(q, st, li, table, lengths_q))
         h = L.apply_norm(params["final_norm"], h, cfg.norm)
         last = lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=1)
         logits = L.unembed(params["embed"], params.get("lm_head"), last,
@@ -1136,14 +1151,17 @@ class PagedServer:
                                self.dtype)
             for li in range(cfg.n_layers):
                 lp = jax.tree.map(lambda a: a[li], self.params["layers"])
-                st = self.store.layer_state(li)
+                # this layer's pages as a one-layer stack
+                st = jax.tree.map(lambda a: a[None],
+                                  self.store.layer_state(li))
                 q, k, v = self._attn_inputs(lp, h, pos)
                 # seed schedule: one scalar append per sequence
                 for bi, (l, row) in enumerate(zip(lengths, rows)):
                     st = self._append_state(
-                        st, jnp.asarray([row[l // self.page]], jnp.int32),
+                        st, 0, jnp.asarray([row[l // self.page]], jnp.int32),
                         jnp.asarray([l % self.page], jnp.int32),
                         k[bi:bi + 1, 0], v[bi:bi + 1, 0])
+                st = jax.tree.map(lambda a: a[0], st)
                 # seed schedule: page table rebuilt per layer
                 max_pages = max(len(r) for r in rows)
                 page_table = jnp.asarray(

@@ -309,6 +309,81 @@ def test_horizon_no_retrace_across_active_counts():
 
 
 # ---------------------------------------------------------------------------
+# the store is carried whole through the layer loop, never scanned over
+# ---------------------------------------------------------------------------
+
+
+def _scans(jaxpr):
+    """Every ``scan`` equation in ``jaxpr`` and the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _scans(sub)
+
+
+def _step_program(srv, program):
+    """(the step function, its arguments) at tiny shapes."""
+    b, pps, h = 2, 4, 4
+    z = jnp.zeros((b,), jnp.int32)
+    table = jnp.zeros((b, pps), jnp.int32)
+    eos = jnp.int32(-1)
+    state = srv.store.device_state()
+    if program == "decode_step":
+        return srv.decode_step, (srv.params, state, table, z, z)
+    if program == "decode_horizon_step":
+        return (lambda *a: srv.decode_horizon_step(*a, horizon=h),
+                (srv.params, state, table, z, z, z, eos))
+    if program == "decode_spec_step":
+        return (lambda *a: srv.decode_spec_step(*a, horizon=h),
+                (srv.params, state, table, z, z, z, eos,
+                 jnp.zeros((b, 16), jnp.int32), z, jax.random.PRNGKey(0),
+                 jnp.float32(0.0), jnp.float32(1.0), z))
+    return srv.prefill_chunk_step, (
+        srv.params, state, jnp.zeros((pps,), jnp.int32),
+        jnp.zeros((1, 8), jnp.int32), jnp.int32(0), jnp.int32(3))
+
+
+@pytest.mark.parametrize("program,page_dtype", [
+    ("decode_horizon_step", "fp32"),
+    ("prefill_chunk_step", "fp32"),
+    ("decode_spec_step", "fp32"),
+    ("decode_step", "fp32"),
+    ("decode_horizon_step", "int8"),
+])
+def test_store_is_carried_not_scanned(program, page_dtype):
+    """No step program slices or restacks the page store per layer: in
+    its jaxpr no ``scan`` has a store leaf (or one layer's slice of one)
+    among its ``xs`` or ``ys``, and the layer scan carries every leaf
+    whole."""
+    cfg, model, params = _tiny_model()
+    srv = PagedServer(model, params, page_size=4, hbm_pages=23,
+                      dtype=jnp.float32, page_dtype=page_dtype)
+    srv._jnp_attention = False          # the chip's path: the kernel
+    stacked = {tuple(a.shape) for a in
+               jax.tree.leaves(srv.store.device_state())}
+    store_shapes = stacked | {s[1:] for s in stacked}
+    fn, args = _step_program(srv, program)
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    carried = set()
+    scans = list(_scans(jaxpr))
+    assert scans
+    for eqn in scans:
+        n_consts, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
+        xs = eqn.invars[n_consts + n_carry:]
+        ys = eqn.outvars[n_carry:]
+        for v in list(xs) + list(ys):
+            assert tuple(v.aval.shape) not in store_shapes, \
+                f"{program}: a scan slices the store ({v.aval})"
+        carried |= {tuple(v.aval.shape)
+                    for v in eqn.invars[n_consts:n_consts + n_carry]}
+    assert stacked <= carried
+
+
+# ---------------------------------------------------------------------------
 # scheduler on horizon boundaries
 # ---------------------------------------------------------------------------
 

@@ -37,18 +37,51 @@ def test_flash_attention(b, h, hkv, s, d, causal, dtype):
     (2, 8, 1, 64, 16, 6, 12),   # MQA
     (1, 16, 8, 128, 8, 16, 16),
 ])
-def test_paged_attention(b, h, hkv, d, page, pps, npage):
+@pytest.mark.parametrize("stacked", [None, "bf16", "int8"])
+def test_paged_attention(b, h, hkv, d, page, pps, npage, stacked):
+    """The 4-D call matches its oracle.  ``stacked``: the layer-indexed
+    call on stacked ``[L, P, page, Hkv, D]`` pages (the serving steps'
+    form) equals the 4-D call on slice ``[li]``, for a middle and the
+    last layer, with not-owned (negative) table entries and stats."""
     ks = jax.random.split(KEY, 5)
     q = jax.random.normal(ks[0], (b, h, d), jnp.float32)
-    kp = jax.random.normal(ks[1], (npage, page, hkv, d), jnp.float32)
-    vp = jax.random.normal(ks[2], (npage, page, hkv, d), jnp.float32)
     pt = jax.random.permutation(ks[3], npage)[:b * pps].reshape(
         b, pps).astype(jnp.int32)
     lens = jax.random.randint(ks[4], (b,), 1, pps * page + 1, jnp.int32)
-    out = ops.paged_attention(q, kp, vp, pt, lens)
-    expect = ref.paged_attention_ref(q, kp, vp, pt, lens)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                               atol=2e-5, rtol=2e-5)
+    if stacked is None:
+        kp = jax.random.normal(ks[1], (npage, page, hkv, d), jnp.float32)
+        vp = jax.random.normal(ks[2], (npage, page, hkv, d), jnp.float32)
+        out = ops.paged_attention(q, kp, vp, pt, lens)
+        expect = ref.paged_attention_ref(q, kp, vp, pt, lens)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                                   atol=2e-5, rtol=2e-5)
+        return
+    from repro.kernels.paged_attention import (paged_attention as pa,
+                                               paged_attention_q8 as pa8)
+    from repro.models.layers import quantize_kv
+    n_layers = 3
+    shape = (n_layers, npage, page, hkv, d)
+    kp = jax.random.normal(ks[1], shape, jnp.float32)
+    vp = jax.random.normal(ks[2], shape, jnp.float32)
+    table = jnp.where(jnp.arange(pps)[None, :] % 3 == 1, -1, pt)
+    if stacked == "int8":
+        (kq, ksc), (vq, vsc) = quantize_kv(kp), quantize_kv(vp)
+
+        def call(li, *pages):
+            return pa8(q, *pages, table, lens, layer=li, interpret=True,
+                       return_stats=True)
+        pages = (kq, vq, ksc, vsc)
+    else:
+        def call(li, *pages):
+            return pa(q.astype(jnp.bfloat16), *pages, table, lens,
+                      layer=li, interpret=True, return_stats=True)
+        pages = (kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16))
+    for li in (1, n_layers - 1):
+        got = call(jnp.int32(li), *pages)
+        want = call(None, *(p[li] for p in pages))
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                          np.asarray(w, np.float32))
 
 
 def test_paged_attention_length_masking():

@@ -5,13 +5,17 @@ that is described and not attached: these tests catch what interpret
 mode cannot (block tiling, VMEM stores, unsupported primitives) at no
 chip time.  Widths are the serving model's (granite-3-2b: 8 KV heads,
 head_dim 64, 16-token pages) and the analytics store's (128-row pages
-of 128 columns).  Nothing runs, so results are checked elsewhere.
+of 128 columns).  One whole decode-horizon program is compiled at the
+benchmark cell's shapes, and its optimized HLO is held to moving no
+copy of the KV store.  Nothing runs, so results are checked elsewhere.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may hold the TPU library, and every test
 worker imports this file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -99,3 +103,71 @@ def test_topk_scan_compiles(one_chip, code, metric):
                                                  metric=metric, scales=s),
                  one_chip, pool, ((EXTENT_PAGES, ROWS), jnp.float32),
                  table, one, query)
+
+
+# the granite-decode-batch cell: batch 8, table width 256, horizon 8,
+# 1,600 stacked pages of 16 tokens
+CELL_B, CELL_PPS, CELL_H, CELL_PAGES = 8, 256, 8, 1600
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def test_decode_horizon_moves_no_store_copy(one_chip):
+    """``decode_horizon_step`` at the cell's shapes: no step copies or
+    slices the store — no rematerialisation, no dynamic-slice or
+    dynamic-slice fusion whose result is one layer's pages or more, and
+    no such ``copy`` but the relayout of each store array into the
+    kernel's layout on entry and back on exit (the store rests in the
+    device's default layout, which puts the pages axis minor).  The
+    temporaries hold the store once, in the kernel's layout, beside
+    less than 1 GB (12.8 GB before the store was carried through the
+    layer loop, appended in place and read at a layer index)."""
+    from repro.configs.base import get_arch
+    from repro.models.api import get_model
+    from repro.runtime.serve import PagedServer
+
+    model = get_model(get_arch("granite_3_2b"), compute_dtype=jnp.bfloat16)
+    cfg = model.cfg
+    srv = PagedServer(model, None, page_size=PAGE, hbm_pages=2,
+                      dtype=jnp.bfloat16)
+    srv._jnp_attention = False
+    # as the server jits it on the chip: interpreting nothing, donating
+    # the store
+    srv._interpret = False
+    step = jax.jit(srv.decode_horizon_step, static_argnames=("horizon",),
+                   donate_argnums=(1,))
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: arg(a.shape, jnp.bfloat16),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    store = (cfg.n_layers, CELL_PAGES, PAGE, cfg.n_kv_heads, cfg.hd)
+    state = {n: arg(store, jnp.bfloat16) for n in ("k", "v")}
+    row = arg((CELL_B,))
+    compiled = step.lower(
+        params, state, arg((CELL_B, CELL_PPS)), row, row, row, arg(()),
+        arg((2,), jnp.uint32), arg((), jnp.float32), arg((), jnp.float32),
+        row, horizon=CELL_H).compile()
+    layer_slice = math.prod(store[1:])
+    moved, relayouts, computation = [], 0, None
+    for line in compiled.as_text().splitlines():
+        if line and not line[0].isspace():
+            computation = line.split()[0]
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, dims, op = m.group(1), m.group(2), m.group(3)
+        shape = tuple(int(x) for x in dims.split(",") if x)
+        store_sized = (shape[-2:] == store[-2:] and
+                       math.prod(shape) >= layer_slice)
+        sliced = op == "dynamic-slice" or name.startswith("dynamic-slice")
+        if (op == "copy" and shape == store and computation == "ENTRY"):
+            relayouts += 1
+        elif "remat" in name or (store_sized and (op == "copy" or sliced)):
+            moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    assert relayouts <= 2 * len(state), relayouts
+    # K and V in the kernel's layout: the 64-wide head dim padded to 128
+    kernel_layout_store = math.prod(store[:-1]) * 128 * 2 * len(state)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < kernel_layout_store + 2 ** 30, temp
