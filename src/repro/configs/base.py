@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
-from typing import Iterator, Optional
+from typing import Iterator, Optional, Tuple
 
 # ---------------------------------------------------------------------------
 # Architecture configuration
@@ -42,8 +42,20 @@ class ArchConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     attn_every: int = 0              # hybrid: shared attn block period
+    ssm_groups: int = 1              # Mamba-2 B/C groups
+    ssm_conv: int = 4                # Mamba-2 causal conv width
+    ssm_chunk: int = 256             # Mamba-2 SSD chunk of a prefill
+    # per-layer kinds, "attention" | "mamba" (block_type "hybrid"); empty:
+    # every layer is the block_type's own
+    layer_types: Tuple[str, ...] = ()
+    norm_eps: float = 1e-6
+    # Granite's scalings; the defaults leave a model unscaled
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None   # None: 1/sqrt(head_dim)
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # structure
-    block_type: str = "transformer"  # transformer | rwkv6 | mamba2_hybrid
+    block_type: str = "transformer"  # transformer | rwkv6 | mamba2_hybrid | hybrid
     encoder_only: bool = False
     causal: bool = True
     frontend: Optional[str] = None   # vision | audio (stubbed per task spec)
@@ -59,6 +71,18 @@ class ArchConfig:
         return self.n_experts > 0
 
     @property
+    def state_layers(self) -> int:
+        """Layers that keep a per-sequence recurrent state (Mamba-2)."""
+        return sum(t == "mamba" for t in self.layer_types)
+
+    @property
+    def attn_layers(self) -> int:
+        """Layers that keep KV pages."""
+        if not self.layer_types:
+            return self.n_layers
+        return sum(t == "attention" for t in self.layer_types)
+
+    @property
     def sub_quadratic(self) -> bool:
         """Archs whose decode state does not grow O(seq * d): SSM/hybrid."""
         return self.block_type in ("rwkv6", "mamba2_hybrid")
@@ -69,6 +93,17 @@ class ArchConfig:
 
     def reduced(self) -> "ArchConfig":
         """CPU-smoke-test configuration of the same family (tiny dims)."""
+        if self.layer_types:
+            # one period of the layer pattern (up to the second
+            # attention layer), at d_model 64
+            at = [i for i, t in enumerate(self.layer_types)
+                  if t == "attention"]
+            n = at[1] - at[0] if len(at) > 1 else len(self.layer_types)
+            return dataclasses.replace(
+                self, n_layers=n, layer_types=self.layer_types[:n],
+                d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
+                d_ff=128, vocab_size=128, ssm_state=16, ssm_head_dim=16,
+                ssm_chunk=16)
         return dataclasses.replace(
             self,
             n_layers=min(self.n_layers, 2 if self.attn_every == 0 else max(2, self.attn_every)),

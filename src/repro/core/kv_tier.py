@@ -16,7 +16,9 @@ The cache is split along the host/device boundary:
     layers at once and one transfer moves a whole stacked page.  The
     jitted serving steps take these arrays, carry them whole through
     their layer loop, append in place and return them; the paged
-    kernel reads them at a layer index.
+    kernel reads them at a layer index.  For models with recurrent
+    layers (Mamba-2) it also holds fixed **state slots**, one per live
+    sequence, beside the pages (:meth:`PageStore.device_state`).
   * :class:`PageTableManager` — host-side policy.  Owns the logical
     (seq_id, page_idx) -> physical mapping, LRU eviction into the host
     tier, pinning, prefetch, per-tier stats, sequence lifetime
@@ -86,6 +88,26 @@ def dequantize_page_kv(codes, scale):
     return codes.astype(jnp.float32) * scale[..., None]
 
 
+def _pow2(n: int) -> int:
+    return 1 << max(0, n - 1).bit_length()
+
+
+def _zero_slot(ssm, conv, slot):
+    def zero(a):
+        # an in-bounds update in place (``.at[].set`` would guard the
+        # index with a select over the whole array)
+        return jax.lax.dynamic_update_slice(
+            a, jnp.zeros((a.shape[0], 1) + a.shape[2:], a.dtype),
+            (0, slot) + (0,) * (a.ndim - 2))
+    return zero(ssm), zero(conv)
+
+
+#: the slot reset, updating the state in place where the backend can
+#: (the CPU ignores donation, with a warning)
+_ZERO_SLOT = {False: jax.jit(_zero_slot),
+              True: jax.jit(_zero_slot, donate_argnums=(0, 1))}
+
+
 @dataclasses.dataclass
 class KVTierStats:
     page_ins: int = 0
@@ -132,11 +154,24 @@ class PageStore:
     materialized fp32 page).  Scales are per slot rather than per page
     so decode appends never requantize already-written positions
     (DESIGN.md §Quantized page format).
+
+    **State slots** (``state_layers`` > 0, hybrid models): ``ssm``
+    [state_layers, state_slots + 1, *ssm_shape] and ``conv``
+    [state_layers, state_slots + 1, *conv_shape], float32, hold each
+    live sequence's recurrent state in the slot the manager gave it
+    (:meth:`PageTableManager.claim_slot`); the last slot is a spare no
+    sequence holds, which the state kernel names for rows it skips.
+    ``rows`` [pow2(state_slots)] int32 names the slot of each row of the
+    next step (:meth:`set_rows`, -1: none).  All three are leaves of
+    :meth:`device_state`, so the step programs carry them with the
+    pages and update the slots in place.
     """
 
     def __init__(self, *, n_layers: int, page_size: int, hbm_pages: int,
                  n_kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
-                 page_dtype: str = "fp32"):
+                 page_dtype: str = "fp32", state_layers: int = 0,
+                 state_slots: int = 0, ssm_shape: Tuple[int, ...] = (),
+                 conv_shape: Tuple[int, ...] = ()):
         if page_dtype not in PAGE_DTYPES:
             raise ValueError(f"page_dtype must be one of {PAGE_DTYPES}, "
                              f"got {page_dtype!r}")
@@ -166,6 +201,16 @@ class PageStore:
             self.v_scale = jnp.zeros(sshape, jnp.float32)
         else:
             self.k_scale = self.v_scale = None
+        self.state_slots = state_slots if state_layers else 0
+        if self.state_slots:
+            # one slot more than sequences: the state kernel's skipped
+            # rows name the last one, which no sequence holds
+            n = self.state_slots + 1
+            self.ssm = jnp.zeros((state_layers, n) + tuple(ssm_shape),
+                                 jnp.float32)
+            self.conv = jnp.zeros((state_layers, n) + tuple(conv_shape),
+                                  jnp.float32)
+            self.rows = jnp.full((_pow2(self.state_slots),), -1, jnp.int32)
 
     @property
     def format_key(self) -> str:
@@ -243,7 +288,23 @@ class PageStore:
         if self.quantized:
             st["ks"] = self.k_scale
             st["vs"] = self.v_scale
+        if self.state_slots:
+            st["ssm"], st["conv"], st["rows"] = self.ssm, self.conv, self.rows
         return st
+
+    def set_rows(self, slots) -> None:
+        """The state slot of each row of the next step (host -> HBM, a
+        few bytes); rows past ``slots`` and negative entries hold none."""
+        rows = np.full((self.rows.shape[0],), -1, np.int32)
+        rows[:len(slots)] = slots
+        self.rows = jnp.asarray(rows)
+
+    def zero_slot(self, slot: int) -> None:
+        """Zero one state slot in every layer (a sequence starts from
+        the zero state)."""
+        fn = _ZERO_SLOT[jax.default_backend() != "cpu"]
+        self.ssm, self.conv = fn(self.ssm, self.conv,
+                                 jnp.asarray(slot, jnp.int32))
 
     def place(self, sharding):
         """Lay the stacked pages out across a device mesh (pool serving:
@@ -274,6 +335,9 @@ class PageStore:
         if self.quantized:
             self.k_scale = state["ks"]
             self.v_scale = state["vs"]
+        if self.state_slots:
+            self.ssm, self.conv, self.rows = (state["ssm"], state["conv"],
+                                              state["rows"])
 
     def is_deleted(self) -> bool:
         """Did a failed donated step consume the window arrays?"""
@@ -397,6 +461,10 @@ class PageTableManager:
         self.stats = KVTierStats()
         self.shard_stats: List[KVTierStats] = [KVTierStats()
                                                for _ in range(n_shards)]
+        # state slots of the live sequences (recurrent layers only)
+        self._slot: Dict[int, int] = {}
+        self._free_slots: List[int] = list(
+            range(store.state_slots - 1, -1, -1))
 
     # -- shard helpers -------------------------------------------------------
 
@@ -433,7 +501,28 @@ class PageTableManager:
             self._prefetched.discard(lkey)
             freed += 1
         self._lengths.pop(seq_id, None)
+        if seq_id in self._slot:
+            self._free_slots.append(self._slot.pop(seq_id))
         return freed
+
+    # -- state slots (models with recurrent layers) --------------------------
+
+    @property
+    def free_slots(self) -> int:
+        return len(self._free_slots)
+
+    def claim_slot(self, seq_id: int) -> int:
+        """Give a sequence a state slot (its own until it is freed);
+        the caller zeroes it.  Raises when every slot is held."""
+        if not self._free_slots:
+            raise RuntimeError(
+                f"all {self.store.state_slots} state slots are held; "
+                "admission must wait for a sequence to retire")
+        self._slot[seq_id] = self._free_slots.pop()
+        return self._slot[seq_id]
+
+    def slot(self, seq_id: int) -> int:
+        return self._slot[seq_id]
 
     # -- capacity accounting (admission control) -----------------------------
 
@@ -594,6 +683,12 @@ class PageTableManager:
             self._prefix_index[self.shard_of_phys(phys)].pop(d, None)
 
     def _evict_one(self, shard: int):
+        if self.store.state_slots:
+            raise RuntimeError(
+                "HBM window full: preempting a sequence to the flash tier "
+                "would have to carry its recurrent state out of its slot, "
+                "and state snapshots and migration are not implemented; "
+                "admission must reserve the window (pages_needed)")
         # LRU among the shard's unpinned, UNSHARED pages (pinned =
         # in-flight step; shared = prefix pages other sequences still
         # read — eviction refuses those until every sharer releases);

@@ -9,7 +9,9 @@ Three paths:
   * default — dense jitted decode (what the dry-run lowers at
     production scale).
   * ``--paged`` — the tiered PagedKVCache + Pallas paged_attention path
-    on one device (the paper's mechanism made concrete).
+    on one device (the paper's mechanism made concrete); hybrid
+    attention + Mamba-2 archs keep one state slot per request beside
+    the pages.
   * ``--pool`` — distributed pool serving: a ``PoolServer`` shard-maps
     the tiered decode over ``--nodes`` devices (one DockerSSD node per
     ``model``-axis shard), fronted by a ``StoragePool`` whose
@@ -34,7 +36,8 @@ import numpy as np
 from repro.configs.base import get_arch
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import get_model
-from repro.runtime.serve import PagedServer, make_serving_fns
+from repro.runtime.serve import (NO_STATE_SNAPSHOTS, PagedServer,
+                                 make_serving_fns)
 
 
 def main(argv=None):
@@ -104,6 +107,13 @@ def main(argv=None):
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if cfg.state_layers and (args.pool or args.speculative):
+        # the state of a recurrent layer lives in one slot per sequence
+        raise SystemExit(
+            f"{cfg.name} has recurrent layers: "
+            f"{'--pool' if args.pool else '--speculative'} would have to "
+            "move or roll back their per-sequence state; "
+            f"{NO_STATE_SNAPSHOTS} (serve it with --paged)")
     model = get_model(cfg, compute_dtype=jnp.float32, moe_no_drop=True)
     params = model.init(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
@@ -141,11 +151,13 @@ def main(argv=None):
         print("control plane:", A.control_plane_terms(pool.driver.stats,
                                                       toks))
     elif args.paged:
-        if cfg.block_type != "transformer":
-            raise SystemExit("--paged demo path supports transformer archs")
+        if cfg.block_type not in ("transformer", "hybrid"):
+            raise SystemExit("--paged serves transformer and hybrid "
+                             "(attention + Mamba-2) archs")
         server = PagedServer(model, params, page_size=args.page_size,
                              hbm_pages=args.hbm_pages,
-                             page_dtype=args.page_dtype)
+                             page_dtype=args.page_dtype,
+                             state_slots=args.requests)
         for i in range(args.requests):
             server.add_request(i, prompts[i],
                                chunk=args.prefill_chunk or None)

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.models import frontends
+from repro.models.hybrid import HybridLM
 from repro.models.mamba2 import Zamba2LM
 from repro.models.rwkv6 import RWKV6LM
 from repro.models.transformer import TransformerLM
@@ -127,7 +128,8 @@ def _filter_kwargs(cls, kw):
 
 
 def get_model(cfg: ArchConfig, compute_dtype=jnp.bfloat16, **kw) -> Model:
-    cls = {"rwkv6": RWKV6LM, "mamba2_hybrid": Zamba2LM}.get(
+    cls = {"rwkv6": RWKV6LM, "mamba2_hybrid": Zamba2LM,
+           "hybrid": HybridLM}.get(
         cfg.block_type, TransformerLM)
     impl = cls(cfg, compute_dtype=compute_dtype, **_filter_kwargs(cls, kw))
     return Model(cfg, impl)

@@ -146,8 +146,12 @@ def _causal_conv(xBC, w, bias):
 
 
 def apply_mamba2_seq(p, x, cfg, conv_state, ssm_state, chunk=64,
-                     unroll=False):
-    """x: [B,S,d].  Returns (out, new_conv_state, new_ssm_state)."""
+                     unroll=False, eps=1e-6, n_valid=None):
+    """x: [B,S,d].  Returns (out, new_conv_state, new_ssm_state).
+
+    ``n_valid`` (a traced scalar) marks positions ``>= n_valid`` as
+    padding: their dt is 0, so they neither decay nor feed the state,
+    and the conv tail is the last ``D_CONV - 1`` inputs before it."""
     b, s, d = x.shape
     d_inner, n_heads, conv_dim = mamba2_dims(cfg)
     ds, dh = cfg.ssm_state, cfg.ssm_head_dim
@@ -157,23 +161,36 @@ def apply_mamba2_seq(p, x, cfg, conv_state, ssm_state, chunk=64,
     full = jnp.concatenate([conv_state.astype(xBC.dtype), xBC], axis=1)
     xBC_conv = _causal_conv(full, p["conv_w"].astype(x.dtype),
                             p["conv_b"].astype(x.dtype))[:, D_CONV - 1:]
-    new_conv_state = full[:, -(D_CONV - 1):]
+    if n_valid is None:
+        new_conv_state = full[:, -(D_CONV - 1):]
+    else:
+        new_conv_state = lax.dynamic_slice_in_dim(full, n_valid,
+                                                  D_CONV - 1, axis=1)
     xs, Bm, Cm = jnp.split(xBC_conv, [d_inner, d_inner + ds], axis=-1)
     xs = xs.reshape(b, s, n_heads, dh)
     dt = jax.nn.softplus(dt.astype(jnp.float32) +
                          p["dt_bias"].astype(jnp.float32))      # [B,S,H]
+    if n_valid is not None:
+        dt = jnp.where((jnp.arange(s) < n_valid)[None, :, None], dt, 0.0)
     da = -jnp.exp(p["a_log"].astype(jnp.float32))[None, None, :] * dt
     y, hT = ssd_chunked(xs.astype(jnp.float32), dt, da,
                         Bm.astype(jnp.float32), Cm.astype(jnp.float32),
                         ssm_state, chunk=chunk, unroll=unroll)
     y = y + xs.astype(jnp.float32) * p["d_skip"].astype(jnp.float32)[None, None, :, None]
     y = y.reshape(b, s, d_inner).astype(x.dtype)
-    y = L.apply_norm(p["gate_norm"], y * jax.nn.silu(z), "rmsnorm")
-    return y @ p["out_proj"].astype(x.dtype), new_conv_state, hT
+    return mamba2_out(p, y, z, eps), new_conv_state, hT
 
 
-def apply_mamba2_step(p, x, cfg, conv_state, ssm_state):
-    """x: [B,d] one token.  conv_state: [B, D_CONV-1, conv_dim]."""
+def mamba2_out(p, y, z, eps=1e-6):
+    """Gated RMSNorm of ``y`` by ``silu(z)``, then ``out_proj``."""
+    y = L.apply_norm(p["gate_norm"], y * jax.nn.silu(z), "rmsnorm", eps)
+    return y @ p["out_proj"].astype(y.dtype)
+
+
+def mamba2_step_in(p, x, cfg, conv_state):
+    """The inputs of one token's state update.  x: [B,d];
+    conv_state: [B, D_CONV-1, conv_dim].  Returns (z, xs [B,H,dh] f32,
+    dt [B,H] f32 after softplus, Bm, Cm [B,ds] f32, new_conv_state)."""
     b, d = x.shape
     d_inner, n_heads, conv_dim = mamba2_dims(cfg)
     ds, dh = cfg.ssm_state, cfg.ssm_head_dim
@@ -183,17 +200,24 @@ def apply_mamba2_step(p, x, cfg, conv_state, ssm_state):
                              axis=1)                             # [B,D_CONV,C]
     conv = jnp.sum(window * p["conv_w"].astype(x.dtype)[None], axis=1)
     xBC_c = jax.nn.silu(conv + p["conv_b"].astype(x.dtype))
-    new_conv_state = window[:, 1:]
     xs, Bm, Cm = jnp.split(xBC_c, [d_inner, d_inner + ds], axis=-1)
     xs = xs.reshape(b, n_heads, dh).astype(jnp.float32)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+    return (z, xs, dt, Bm.astype(jnp.float32), Cm.astype(jnp.float32),
+            window[:, 1:])
+
+
+def apply_mamba2_step(p, x, cfg, conv_state, ssm_state):
+    """x: [B,d] one token.  conv_state: [B, D_CONV-1, conv_dim]."""
+    b, d = x.shape
+    d_inner = mamba2_dims(cfg)[0]
+    z, xs, dt, Bm, Cm, new_conv_state = mamba2_step_in(p, x, cfg,
+                                                       conv_state)
     da = -jnp.exp(p["a_log"].astype(jnp.float32))[None, :] * dt
-    y, new_ssm = ssd_step(xs, dt, da, Bm.astype(jnp.float32),
-                          Cm.astype(jnp.float32), ssm_state)
+    y, new_ssm = ssd_step(xs, dt, da, Bm, Cm, ssm_state)
     y = y + xs * p["d_skip"].astype(jnp.float32)[None, :, None]
     y = y.reshape(b, d_inner).astype(x.dtype)
-    y = L.apply_norm(p["gate_norm"], y * jax.nn.silu(z), "rmsnorm")
-    return y @ p["out_proj"].astype(x.dtype), new_conv_state, new_ssm
+    return mamba2_out(p, y, z), new_conv_state, new_ssm
 
 
 # ---------------------------------------------------------------------------

@@ -41,7 +41,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.kv_tier import PageStore, PageTableManager
 from repro.runtime import sharding as shd
-from repro.runtime.serve import PagedServer, combine_partials
+from repro.runtime.serve import (NO_STATE_SNAPSHOTS, PagedServer,
+                                 combine_partials)
 
 POOL_AXIS = "model"
 
@@ -68,6 +69,11 @@ class PoolServer(PagedServer):
                  policy: str = "placed", prefix_cache: bool = True,
                  page_dtype: str = "fp32",
                  hbm_bytes_per_node: Optional[int] = None):
+        if model.cfg.state_layers:
+            raise ValueError(
+                "PoolServer cannot serve a model with recurrent layers: "
+                "the pool would have to own and move each sequence's "
+                "state slots; " + NO_STATE_SNAPSHOTS)
         if policy not in ("placed", "striped"):
             raise ValueError(f"unknown placement policy {policy!r}")
         if active is not None and policy != "placed":

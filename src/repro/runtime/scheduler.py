@@ -154,10 +154,13 @@ class ContinuousBatcher:
         return self.server.pages_needed(len(req.prompt) + req.max_tokens)
 
     def _window_has_room(self, req: Request) -> bool:
+        """Room for ``req``'s whole reservation in the HBM window, and a
+        state slot for it where the model has recurrent layers."""
         pinned_now = sum(self._pages_needed(r) for r in self.active.values())
         pinned_now += sum(self._pages_needed(r)
                           for r in self.prefilling.values())
-        return pinned_now + self._pages_needed(req) <= self.server.hbm_pages
+        return (pinned_now + self._pages_needed(req) <= self.server.hbm_pages
+                and self.server.has_state_slot())
 
     def _prompt_of(self, req: Request) -> np.ndarray:
         """The tokens a (re-)prefill must write: the prompt plus any

@@ -51,6 +51,12 @@ temperature/top-p Gumbel sampling on a per-step PRNG key
 (``SamplingConfig``), with rejection-sampling acceptance so
 speculative sampling stays distribution-correct (DESIGN.md
 §Speculative decoding).
+
+**Hybrid models** (attention + Mamba-2 layers, granite-4.0-h) run the
+same programs: the attention layers on pages, the Mamba layers on one
+state slot per sequence carried beside them — prefill runs the chunked
+SSD from the slot, decode the ``ssm_state_update`` kernel (DESIGN.md
+§Hybrid models).
 """
 from __future__ import annotations
 
@@ -71,10 +77,20 @@ from repro.kernels import ops, ref as kref
 from repro.kernels.paged_attention import (
     paged_attention as _paged_inner,
     paged_attention_q8 as _paged_q8_inner)
+from repro.kernels.ssm_state import ssm_state_update
 from repro.models import layers as L
+from repro.models.hybrid import layer_runs
+from repro.models.mamba2 import (D_CONV, apply_mamba2_seq, mamba2_dims,
+                                 mamba2_out, mamba2_step_in, ssd_step)
 from repro.runtime import sharding as shd, tracing
 
 NEG_INF = -1e30
+
+#: what a model with recurrent layers cannot do here yet: its state
+#: lives in one slot per sequence, with no copy at a prefix boundary and
+#: no way to move it
+NO_STATE_SNAPSHOTS = ("state snapshots at prefix boundaries and state "
+                      "migration are not implemented")
 
 
 def paged_attention_partial(q, k_pages, v_pages, local_table, col_owned,
@@ -324,18 +340,40 @@ class PagedServer:
     one table per batch.  Batch size and table width are bucketed to
     powers of two, so the decode step compiles O(log) times, not per
     shape.
+
+    A hybrid model (``cfg.layer_types``: attention and Mamba-2 layers,
+    ``models/hybrid.py``) keeps pages for its attention layers only and
+    its Mamba layers' recurrent state in ``state_slots`` per-sequence
+    slots beside them (``PageStore``): a slot is claimed and zeroed at
+    admission and released with the sequence, and the step programs
+    carry the slots with the pages (:meth:`_hybrid_stack`).  Nothing
+    copies a slot, so what would need a copy of the state refuses such
+    a model: the prefix cache, speculation, preemption to the flash
+    tier and the pool (``NO_STATE_SNAPSHOTS``).
     """
 
     def __init__(self, model, params, *, page_size: int = 16,
                  hbm_pages: Optional[int] = None, dtype=jnp.float32,
                  hbm_pages_per_layer: Optional[int] = None,
-                 prefix_cache: bool = True, page_dtype: str = "fp32",
-                 hbm_bytes: Optional[int] = None):
+                 prefix_cache: Optional[bool] = None,
+                 page_dtype: str = "fp32",
+                 hbm_bytes: Optional[int] = None, state_slots: int = 8):
         if page_dtype not in PAGE_DTYPES:
             raise ValueError(f"page_dtype must be one of {PAGE_DTYPES}, "
                              f"got {page_dtype!r}")
         self.model = model
         self.cfg = model.cfg
+        # recurrent (Mamba-2) layers keep one state slot per live
+        # sequence beside the KV pages of the attention layers
+        self.has_state = self.cfg.state_layers > 0
+        self.state_slots = state_slots if self.has_state else 0
+        if prefix_cache is None:
+            prefix_cache = not self.has_state
+        elif prefix_cache and self.has_state:
+            raise ValueError(
+                "the prefix page cache cannot serve a model with recurrent "
+                "layers: a hit would skip the state of the shared prefix; "
+                + NO_STATE_SNAPSHOTS)
         self.params = params
         self.dtype = dtype
         self.page = page_size
@@ -346,7 +384,7 @@ class PagedServer:
             # holds however many (dtype-aware) stacked pages fit — the
             # quantized format's 2-4x page-count payoff at equal HBM
             pb = PageStore.stacked_page_bytes(
-                n_layers=self.cfg.n_layers, page_size=page_size,
+                n_layers=self.cfg.attn_layers, page_size=page_size,
                 n_kv_heads=self.cfg.n_kv_heads, head_dim=self.cfg.hd,
                 dtype=dtype, page_dtype=page_dtype)
             hbm_pages = max(1, int(hbm_bytes) // pb)
@@ -408,10 +446,21 @@ class PagedServer:
         """The store the config prescribes (used at init and when a failed
         donated step voids the window)."""
         cfg = self.cfg
-        return PageStore(n_layers=cfg.n_layers, page_size=self.page,
+        state = {}
+        if self.has_state:
+            d_inner, n_heads, conv_dim = mamba2_dims(cfg)
+            state = dict(state_layers=cfg.state_layers,
+                         state_slots=self.state_slots,
+                         ssm_shape=(n_heads, cfg.ssm_head_dim,
+                                    cfg.ssm_state),
+                         # the tail flat: a [.., 3, C] store would be laid
+                         # out with the 3 minor, padded to 128 lanes
+                         conv_shape=((D_CONV - 1) * conv_dim,))
+        return PageStore(n_layers=cfg.attn_layers, page_size=self.page,
                          hbm_pages=self.hbm_pages,
                          n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd,
-                         dtype=self.dtype, page_dtype=self.page_dtype)
+                         dtype=self.dtype, page_dtype=self.page_dtype,
+                         **state)
 
     def _new_table(self) -> PageTableManager:
         """Table-manager factory (PoolServer overrides with a sharded
@@ -422,6 +471,11 @@ class PagedServer:
 
     def pages_needed(self, n_tokens: int) -> int:
         return self.table.pages_needed(n_tokens)
+
+    def has_state_slot(self) -> bool:
+        """Can one more sequence be admitted as far as state slots go
+        (always, for a model without recurrent layers)?"""
+        return not self.has_state or self.table.free_slots > 0
 
     def sequence_ids(self) -> List[int]:
         return list(self._seqs)
@@ -483,24 +537,53 @@ class PagedServer:
     def _attn_inputs(self, lp, h, positions):
         """Pre-norm -> q/k/v projections -> RoPE at ``positions``."""
         cfg = self.cfg
-        a = L.apply_norm(lp["attn_norm"], h, cfg.norm)
+        a = L.apply_norm(lp["attn_norm"], h, cfg.norm, cfg.norm_eps)
         q, k, v = L._qkv(lp["attn"], a, cfg)
         if cfg.rope:
             q = L.apply_rope(q, positions, cfg.rope_theta)
             k = L.apply_rope(k, positions, cfg.rope_theta)
+        if cfg.attention_multiplier is not None:
+            # the kernel scales scores by 1/sqrt(D); the model's scale
+            # rides on q
+            q = q * (cfg.attention_multiplier * math.sqrt(cfg.hd))
         return q, k, v
+
+    def _residual(self, x):
+        """A residual branch at the model's ``residual_multiplier``."""
+        r = self.cfg.residual_multiplier
+        return x if r == 1.0 else x * r
 
     def _attn_out_ffn(self, lp, h, o_flat):
         """Attention output-projection residual + FFN residual.
         o_flat: [B, S, H*D]."""
+        h = h + self._residual(o_flat @ lp["attn"]["wo"].astype(h.dtype))
+        return self._ffn(lp, h)
+
+    def _ffn(self, lp, h):
+        """The FFN residual of a layer."""
         cfg = self.cfg
-        h = h + o_flat @ lp["attn"]["wo"].astype(h.dtype)
-        m = L.apply_norm(lp["mlp_norm"], h, cfg.norm)
+        m = L.apply_norm(lp["mlp_norm"], h, cfg.norm, cfg.norm_eps)
         if cfg.is_moe:
             mo, _ = L.apply_moe(lp["mlp"], m, cfg, no_drop=True)
         else:
             mo = L.apply_mlp(lp["mlp"], m, cfg.act)
-        return h + mo
+        return h + self._residual(mo)
+
+    def _embed(self, params, tokens):
+        h = L.embed_tokens(params["embed"], tokens, self.dtype)
+        m = self.cfg.embedding_multiplier
+        return h if m == 1.0 else h * m
+
+    def _final_norm(self, params, h):
+        cfg = self.cfg
+        return L.apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
+
+    def _unembed(self, params, h):
+        cfg = self.cfg
+        logits = L.unembed(params["embed"], params.get("lm_head"), h,
+                           cfg.tie_embeddings)
+        s = cfg.logits_scaling
+        return logits if s == 1.0 else logits / s
 
     # -- jitted device programs ----------------------------------------------
 
@@ -518,8 +601,9 @@ class PagedServer:
                 k_new, self.store.qmax, self.store.code_dtype)
             new["v"], new["vs"] = quantize_page_kv(
                 v_new, self.store.qmax, self.store.code_dtype)
-        return {n: a.at[li, tgt, offs].set(new[n].astype(a.dtype),
-                                           mode="drop")
+        return {n: (a.at[li, tgt, offs].set(new[n].astype(a.dtype),
+                                            mode="drop")
+                    if n in new else a)
                 for n, a in st.items()}
 
     def _kernel_attention(self, q, st, li, page_table, lengths,
@@ -553,7 +637,7 @@ class PagedServer:
                                       lengths, return_stats=True)
 
     def _layer_stack(self, params, h, state, positions, tgt, offs,
-                     attention):
+                     attention, live=None, n_valid=None):
         """The layer loop every step program runs: one ``lax.scan`` with
         carry ``(h, state)`` over ``(params["layers"], layer index)``.
         The stacked page state is carried whole — never a scan input or
@@ -564,8 +648,13 @@ class PagedServer:
 
         h: [B, S, d] for the S new positions of each of B rows
         (``positions`` [B, S] or broadcastable); tgt/offs: [B*S] append
-        targets.  Returns (h, state)."""
+        targets.  A model with recurrent layers runs
+        :meth:`_hybrid_stack` (``live``/``n_valid`` are its).  Returns
+        (h, state)."""
         cfg = self.cfg
+        if self.has_state:
+            return self._hybrid_stack(params, h, state, positions, tgt,
+                                      offs, attention, live, n_valid)
         b, s = h.shape[:2]
         n = b * s
 
@@ -585,6 +674,138 @@ class PagedServer:
             body, (h, state),
             (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
         return h, state
+
+    def _hybrid_stack(self, params, h, state, positions, tgt, offs,
+                      attention, live, n_valid):
+        """The layer loop of a model with attention and Mamba-2 layers
+        (``cfg.layer_types``): one ``lax.scan`` over the periods of the
+        layer pattern, whose body runs each run of one kind as a loop
+        over that kind's stacked layers, indexed from the whole stack.
+        The state — KV pages of the attention layers, state slots of
+        the Mamba layers — is carried whole, as in :meth:`_layer_stack`:
+        attention layer ``ai`` appends at ``[ai, tgt, offs]`` and reads
+        through ``attention``; Mamba layer ``mi`` reads and writes its
+        rows' slots at ``[mi, slot]`` in place.
+
+        Row ``r``'s slot is ``state["rows"][r]``; a row that ``live``
+        ([B] bool, decode) marks dead, or a prefill chunk with no valid
+        token, updates no slot.  S > 1 is a prefill chunk of one
+        sequence whose positions from ``n_valid`` on are padding."""
+        cfg = self.cfg
+        b, s = h.shape[:2]
+        n = b * s
+        n_periods, runs = layer_runs(cfg.layer_types)
+        per = {k: sum(c for t, c in runs if t == k)
+               for k in ("attention", "mamba")}
+        slots = state["rows"][:b]
+        if live is not None:
+            slots = jnp.where(live, slots, -1)
+        if n_valid is not None:
+            slots = jnp.where(n_valid > 0, slots, -1)
+
+        def layer_of(stack, i):
+            return jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False),
+                params[stack])
+
+        def attn_layer(ai, carry):
+            hh, st = carry
+            lp = layer_of("attn_layers", ai)
+            q, k, v = self._attn_inputs(lp, hh, positions)
+            st = self._append_state(st, ai, tgt, offs,
+                                    k.reshape(n, cfg.n_kv_heads, cfg.hd),
+                                    v.reshape(n, cfg.n_kv_heads, cfg.hd))
+            o = attention(q.reshape(n, cfg.n_heads, cfg.hd)
+                          .astype(self.dtype), st, ai)
+            return self._attn_out_ffn(lp, hh, o.reshape(b, s, -1)), st
+
+        def mamba_layer(mi, carry):
+            hh, st = carry
+            lp = layer_of("mamba_layers", mi)
+            a = L.apply_norm(lp["norm"], hh, "rmsnorm", cfg.norm_eps)
+            if s == 1:
+                o, st = self._mamba_step(lp["mamba"], a[:, 0], st, mi, slots)
+                o = o[:, None]
+            else:
+                o, st = self._mamba_chunk(lp["mamba"], a, st, mi, slots[0],
+                                          n_valid)
+            return self._ffn(lp, hh + self._residual(o)), st
+
+        layer_fns = {"attention": attn_layer, "mamba": mamba_layer}
+
+        def period(carry, p):
+            seen = {"attention": 0, "mamba": 0}
+            for kind, count in runs:
+                fn, base = layer_fns[kind], p * per[kind] + seen[kind]
+                if count == 1:
+                    carry = fn(base, carry)
+                else:
+                    carry = lax.fori_loop(
+                        0, count,
+                        lambda j, c, fn=fn, base=base: fn(base + j, c),
+                        carry)
+                seen[kind] += count
+            return carry, None
+
+        (h, state), _ = lax.scan(period, (h, state),
+                                 jnp.arange(n_periods, dtype=jnp.int32))
+        return h, state
+
+    def _mamba_step(self, p, a, st, mi, slots):
+        """One token of Mamba layer ``mi`` for every row: the conv over
+        the slot's carried tail, then the state update — the
+        ``ssm_state_update`` kernel, or ``ssd_step`` in jnp where the
+        attention runs in jnp too.  Rows with a negative slot read the
+        store's spare slot and write nothing.  a: [B, d] normed input.
+        Returns (out [B, d], state)."""
+        cfg = self.cfg
+        n_total = st["ssm"].shape[1]
+        read = jnp.where(slots >= 0, slots, n_total - 1)
+        write = jnp.where(slots >= 0, slots, n_total)   # dropped
+        b = a.shape[0]
+        z, xs, dt, bm, cm, tail = mamba2_step_in(
+            p, a, cfg, st["conv"][mi, read].reshape(b, D_CONV - 1, -1))
+        conv = st["conv"].at[mi, write].set(
+            tail.reshape(b, -1).astype(jnp.float32), mode="drop")
+        A = -jnp.exp(p["a_log"].astype(jnp.float32))
+        D = p["d_skip"].astype(jnp.float32)
+        if self._jnp_attention:
+            y, new = ssd_step(xs, dt, dt * A[None], bm, cm,
+                              st["ssm"][mi, read])
+            y = y + xs * D[None, :, None]
+            ssm = st["ssm"].at[mi, write].set(new, mode="drop")
+        else:
+            y, ssm = ssm_state_update(st["ssm"], xs, dt, A, bm, cm, D,
+                                      slots, mi, interpret=self._interpret)
+        y = y.reshape(b, -1).astype(a.dtype)
+        return mamba2_out(p, y, z, cfg.norm_eps), {**st, "ssm": ssm,
+                                                   "conv": conv}
+
+    def _mamba_chunk(self, p, a, st, mi, slot, n_valid):
+        """A prefill chunk of one sequence through Mamba layer ``mi``:
+        the chunked SSD (``mamba_chunk_size``) from the slot's state and
+        conv tail; the final state and the tail at the chunk's true
+        length go back to the slot.  a: [1, C, d]."""
+        cfg = self.cfg
+        slot = jnp.where(slot >= 0, slot, st["ssm"].shape[1] - 1)
+
+        def read(a):
+            return lax.dynamic_slice(a, (mi, slot) + (0,) * (a.ndim - 2),
+                                     (1, 1) + a.shape[2:])[0]
+
+        def write(a, x):
+            # an in-bounds update in place (``.at[].set`` would guard
+            # the index with a select over the whole array)
+            return lax.dynamic_update_slice(
+                a, x.astype(a.dtype)[None], (mi, slot) + (0,) * (a.ndim - 2))
+
+        out, tail, h_t = apply_mamba2_seq(
+            p, a, cfg, read(st["conv"]).reshape(1, D_CONV - 1, -1),
+            read(st["ssm"]),
+            chunk=min(cfg.ssm_chunk, a.shape[1]), eps=cfg.norm_eps,
+            n_valid=n_valid)
+        return out, {**st, "ssm": write(st["ssm"], h_t),
+                     "conv": write(st["conv"], tail.reshape(1, -1))}
 
     def decode_step(self, params, state, page_table, lengths, tokens):
         """One fused decode step for the whole active batch — the
@@ -665,7 +886,6 @@ class PagedServer:
         *inside* the traced switch, so toggling sampling never retraces
         and greedy outputs stay bit-identical to the key-free program.
         """
-        cfg = self.cfg
 
         def step(carry, i):
             state, lengths, tokens, budget = carry
@@ -678,13 +898,13 @@ class PagedServer:
             tgt = append_target(phys, valid)
             new_lengths = lengths + valid.astype(jnp.int32)
 
-            h = L.embed_tokens(params["embed"], tokens[:, None], self.dtype)
+            h = self._embed(params, tokens[:, None])
             h, state = self._layer_stack(
                 params, h, state, pos, tgt, offs,
-                lambda q, st, li: attention(q, st, li, new_lengths))
-            h = L.apply_norm(params["final_norm"], h, cfg.norm)
-            logits = L.unembed(params["embed"], params.get("lm_head"), h,
-                               cfg.tie_embeddings)[:, 0]
+                lambda q, st, li: attention(q, st, li, new_lengths),
+                live=valid)
+            logits = self._unembed(params,
+                                   self._final_norm(params, h))[:, 0]
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             if key is not None:
                 # lax.cond (not where): greedy passes must not pay the
@@ -797,7 +1017,6 @@ class PagedServer:
         per-sequence drafted-count row, ONE device->host transfer —
         and the page state).
         """
-        cfg = self.cfg
         b = tokens.shape[0]
         pps = page_table.shape[1]
         hzn = horizon
@@ -818,13 +1037,12 @@ class PagedServer:
         # per-position causal extent; 0 fully masks dead positions
         row_lengths = jnp.where(append_ok, pos + 1, 0).reshape(-1)
 
-        h = L.embed_tokens(params["embed"], fed, self.dtype)
+        h = self._embed(params, fed)
         h, state = self._layer_stack(
             params, h, state, pos, tgt, offs,
             lambda q, st, li: attention(q, st, li, row_lengths))
-        h = L.apply_norm(params["final_norm"], h, cfg.norm)
-        logits = L.unembed(params["embed"], params.get("lm_head"), h,
-                           cfg.tie_embeddings).astype(jnp.float32)
+        logits = self._unembed(params, self._final_norm(params, h)
+                               ).astype(jnp.float32)
 
         greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         # candidate that position j's logits verify: d_{j+1}; the last
@@ -912,7 +1130,6 @@ class PagedServer:
         [C, H, D]`` closes the paged-attention contract over layer
         ``li`` of the stacked state.
         """
-        cfg = self.cfg
         c = tokens.shape[1]
         pps = page_row.shape[0]
         pos_i = jnp.arange(c, dtype=jnp.int32)
@@ -926,14 +1143,14 @@ class PagedServer:
         lengths_q = jnp.where(valid_w, wpos + 1, 0)
         table = jnp.broadcast_to(page_row[None, :], (c, pps))
 
-        h = L.embed_tokens(params["embed"], tokens, self.dtype)
+        h = self._embed(params, tokens)
         h, state = self._layer_stack(
             params, h, state, positions, phys_w, offs,
-            lambda q, st, li: attention(q, st, li, table, lengths_q))
-        h = L.apply_norm(params["final_norm"], h, cfg.norm)
+            lambda q, st, li: attention(q, st, li, table, lengths_q),
+            n_valid=n_valid)
+        h = self._final_norm(params, h)
         last = lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=1)
-        logits = L.unembed(params["embed"], params.get("lm_head"), last,
-                           cfg.tie_embeddings)[0, 0]
+        logits = self._unembed(params, last)[0, 0]
         return logits.astype(jnp.float32), state
 
     def prefill_chunk_step(self, params, state, page_row, tokens, start,
@@ -966,6 +1183,11 @@ class PagedServer:
         the lazy match can only cover more)."""
         prompt = np.asarray(prompt, np.int32)
         assert int(prompt.shape[0]) >= 1, "empty prompt"
+        if self.has_state:
+            with tracing.span("server.state.reset"):
+                # the sequence starts from the zero state in a slot of
+                # its own, released by free_sequence
+                self.store.zero_slot(self.table.claim_slot(seq_id))
         self.table.add_sequence(seq_id)
         self._seqs.append(seq_id)
         self._prefill_state[seq_id] = prompt
@@ -1032,6 +1254,7 @@ class PagedServer:
             counts.update(tokens=c, final=start + c == s)
             with tracing.span("server.prefill.dispatch"):
                 try:
+                    self._set_rows([seq_id])
                     logits, state = self._chunk_jit(
                         self.params, self.store.device_state(),
                         jnp.asarray(row), jnp.asarray(tokens),
@@ -1085,6 +1308,11 @@ class PagedServer:
 
     # -- one committed batched step -------------------------------------------
 
+    def _set_rows(self, seqs: List[int]) -> None:
+        """Name each row's state slot for the next step program."""
+        if self.has_state:
+            self.store.set_rows([self.table.slot(s) for s in seqs])
+
     def _plan_step(self, seqs: List[int]):
         """Host-side page management for one decode step: make every
         active page resident + pinned, then build the padded device
@@ -1111,6 +1339,7 @@ class PagedServer:
         seqs = list(tokens)
         page_table, lengths = self._plan_step(seqs)
         try:
+            self._set_rows(seqs)
             toks = np.zeros((lengths.shape[0],), np.int32)
             toks[:len(seqs)] = [tokens[s] for s in seqs]
             logits, state = self._decode_jit(
@@ -1139,6 +1368,9 @@ class PagedServer:
         NOT commit — used for equivalence tests and as the benchmark
         baseline.  Returns logits [B, V] in ``tokens`` order."""
         cfg = self.cfg
+        if self.has_state:
+            raise NotImplementedError("the eager reference step serves "
+                                      "attention-only models")
         seqs = list(tokens)
         try:
             rows = [self.table.prepare_append(s) for s in seqs]
@@ -1263,6 +1495,7 @@ class PagedServer:
             counts.update(grid)
             try:
                 with tracing.span("server.horizon.dispatch"):
+                    self._set_rows(seqs)
                     toks = np.zeros((lengths.shape[0],), np.int32)
                     toks[:len(seqs)] = [tokens[s] for s in seqs]
                     eos = np.int32(eos_id if eos_id is not None else -1)
@@ -1290,6 +1523,10 @@ class PagedServer:
                         # step feeds one token and emits one); rollback
                         # the unused tail of the reservation
                         self.table.commit_horizon(s, len(got))
+                    if self.has_state:
+                        # each emitted token updated its row's state once
+                        counts["state_rows"] = sum(len(g)
+                                                   for g in out.values())
             except Exception:
                 self._recover_store()
                 # store intact (the failure was not a donated-buffer
@@ -1348,6 +1585,7 @@ class PagedServer:
         speculates (a probe — if the workload turns repetitive the EMA
         recovers and the gate reopens).
         """
+        self._refuse_speculation()
         sampling = sampling or GREEDY
         seqs = list(tokens)
         if _key is None:
@@ -1436,6 +1674,13 @@ class PagedServer:
             self.table.unpin_all()
         return out
 
+    def _refuse_speculation(self) -> None:
+        if self.has_state:
+            raise ValueError(
+                "speculative decoding cannot serve a model with recurrent "
+                "layers: a rejected draft would have to roll the state "
+                "back; " + NO_STATE_SNAPSHOTS)
+
     def speculation_stats(self) -> Dict[str, object]:
         """Speculative telemetry: pass/fallback counts, drafted vs
         accepted candidates (``alpha`` = acceptance rate), and the
@@ -1498,6 +1743,7 @@ class PagedServer:
                     "sampling=SamplingConfig(temperature=..., top_p=...)")
         sampling = sampling or GREEDY
         if speculative:
+            self._refuse_speculation()
             if horizon is None:
                 horizon = 8
             if horizon < 2:

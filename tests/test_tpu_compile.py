@@ -5,9 +5,10 @@ that is described and not attached: these tests catch what interpret
 mode cannot (block tiling, VMEM stores, unsupported primitives) at no
 chip time.  Widths are the serving model's (granite-3-2b: 8 KV heads,
 head_dim 64, 16-token pages) and the analytics store's (128-row pages
-of 128 columns).  One whole decode-horizon program is compiled at the
-benchmark cell's shapes, and its optimized HLO is held to moving no
-copy of the KV store.  Nothing runs, so results are checked elsewhere.
+of 128 columns).  The whole decode-horizon program of each benchmark
+cell is compiled at the cell's shapes, and its optimized HLO is held to
+moving no copy of the KV store (nor, for the hybrid model, of its state
+slots).  Nothing runs, so results are checked elsewhere.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may hold the TPU library, and every test
@@ -171,3 +172,73 @@ def test_decode_horizon_moves_no_store_copy(one_chip):
     kernel_layout_store = math.prod(store[:-1]) * 128 * 2 * len(state)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < kernel_layout_store + 2 ** 30, temp
+
+
+# the granite-h-micro-reasoning32 cell: batch 32, table width 512,
+# horizon 8, 8,192 pages of 16 tokens for the 4 attention layers, 32
+# state slots (and the spare) for the 36 Mamba-2 layers
+HYB_B, HYB_PPS, HYB_PAGES, HYB_SLOTS = 32, 512, 8192, 32
+
+
+def test_hybrid_horizon_moves_no_state_copy(one_chip):
+    """``decode_horizon_step`` of granite-4.0-h-micro at the cell's
+    shapes: the state slots are read and written in place by the
+    ``ssm_state_update`` kernel — no copy, dynamic slice or
+    rematerialisation of a layer's slots or pages or more.  The only
+    whole-array copies are the relayouts on entry and exit of the KV
+    store (as in the dense program) and of the small conv tails."""
+    from repro.configs.base import get_arch
+    from repro.models.api import get_model
+    from repro.runtime.serve import PagedServer
+
+    model = get_model(get_arch("granite-4.0-h-micro"),
+                      compute_dtype=jnp.bfloat16)
+    cfg = model.cfg
+    srv = PagedServer(model, None, page_size=PAGE, hbm_pages=2,
+                      dtype=jnp.bfloat16, state_slots=1)
+    srv._jnp_attention = False
+    srv._interpret = False
+    step = jax.jit(srv.decode_horizon_step, static_argnames=("horizon",),
+                   donate_argnums=(1,))
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: arg(a.shape, jnp.bfloat16),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pages = (cfg.attn_layers, HYB_PAGES, PAGE, cfg.n_kv_heads, cfg.hd)
+    ssm = (cfg.state_layers, HYB_SLOTS + 1, 64, 64, 128)
+    conv = (cfg.state_layers, HYB_SLOTS + 1, 3 * 4352)
+    state = {"k": arg(pages, jnp.bfloat16), "v": arg(pages, jnp.bfloat16),
+             "ssm": arg(ssm, jnp.float32), "conv": arg(conv, jnp.float32),
+             "rows": arg((HYB_SLOTS,))}
+    row = arg((HYB_B,))
+    compiled = step.lower(
+        params, state, arg((HYB_B, HYB_PPS)), row, row, row, arg(()),
+        arg((2,), jnp.uint32), arg((), jnp.float32), arg((), jnp.float32),
+        row, horizon=CELL_H).compile()
+    text = compiled.as_text()
+    assert "ssm_state_update" in text
+    moved, relayouts, computation = [], 0, None
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            computation = line.split()[0]
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, dims, op = m.group(1), m.group(2), m.group(3)
+        shape = tuple(int(x) for x in dims.split(",") if x)
+        big = any(shape[-len(s) + 2:] == s[2:] and
+                  math.prod(shape) >= math.prod(s[1:])
+                  for s in (pages, ssm))
+        sliced = op == "dynamic-slice" or name.startswith("dynamic-slice")
+        if op == "copy" and shape in (pages, conv) and \
+                computation == "ENTRY":
+            relayouts += 1
+        elif "remat" in name or (big and (op == "copy" or sliced)):
+            moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    assert relayouts <= 6, relayouts
+    kernel_layout_pages = math.prod(pages[:-1]) * 128 * 2 * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < kernel_layout_pages + 2 ** 31, temp
