@@ -76,7 +76,8 @@ from repro.core.kv_tier import (PAGE_DTYPES, PageStore, PageTableManager,
 from repro.kernels import ops, ref as kref
 from repro.kernels.paged_attention import (
     paged_attention as _paged_inner,
-    paged_attention_q8 as _paged_q8_inner)
+    paged_attention_q8 as _paged_q8_inner,
+    pages_per_block)
 from repro.kernels.ssm_state import ssm_state_update
 from repro.models import layers as L
 from repro.models.hybrid import layer_runs
@@ -1427,7 +1428,9 @@ class PagedServer:
         of two, so horizons over 3 and 4 active sequences share one
         compiled program.  Also returns the attention grid's counts:
         ``bucket_rows`` x ``table_width`` page slots, of which ``pages``
-        hold (or will hold) KV."""
+        hold (or will hold) KV, and the kernel's ``block_grid`` of
+        ``bucket_rows`` x blocks of ``pages_per_block`` pages, of which
+        ``blocks`` hold KV."""
         try:
             rows = [self.table.reserve_horizon(s, budgets[s]) for s in seqs]
         except Exception:
@@ -1449,8 +1452,11 @@ class PagedServer:
         lens[:len(seqs)] = lengths
         buds = np.zeros((b2,), np.int32)
         buds[:len(seqs)] = [budgets[s] for s in seqs]
+        ppb = pages_per_block(pps, self.page)
         grid = {"bucket_rows": b2, "table_width": pps,
-                "pages": sum(len(r) for r in rows)}
+                "pages": sum(len(r) for r in rows),
+                "blocks": sum(-(-len(r) // ppb) for r in rows),
+                "block_grid": b2 * -(-pps // ppb)}
         return (jnp.asarray(table), jnp.asarray(lens), jnp.asarray(buds),
                 grid)
 

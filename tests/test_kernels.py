@@ -31,30 +31,61 @@ def test_flash_attention(b, h, hkv, s, d, causal, dtype):
                                atol=tol, rtol=tol)
 
 
+#: tables wide enough for several of the kernel's blocks per row, (b,
+#: page, pps), whose rows take the lengths of ``_lengths`` at its edges
+WIDE = {(5, 16, 48), (3, 8, 100)}
+
+
+def _lengths(key, b, page, pps):
+    """[b] row lengths: random in [1, pps * page], or for a ``WIDE``
+    table a block edge, mid-block and mid-page, a page edge inside a
+    block, a row of length 0 and the full table (``pps`` need not be a
+    multiple of the block's pages)."""
+    if (b, page, pps) not in WIDE:
+        return jax.random.randint(key, (b,), 1, pps * page + 1, jnp.int32)
+    from repro.kernels.paged_attention import pages_per_block
+    bk = pages_per_block(pps, page) * page
+    edges = [bk, bk + bk // 2 + page // 2, bk + 2 * page, 0, pps * page]
+    assert max(edges) == pps * page and bk < pps * page
+    return jnp.asarray(edges[:b], jnp.int32)
+
+
+def _assert_rows(out, expect, lens, tol):
+    """``out`` matches ``expect`` on rows that hold tokens; a row of
+    length 0 attends to nothing and reads 0 (the oracles spread it
+    evenly over the table instead)."""
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(np.asarray(out)[live],
+                               np.asarray(expect)[live], atol=tol, rtol=tol)
+    assert not np.asarray(out)[~live].any()
+
+
 @pytest.mark.parametrize("b,h,hkv,d,page,pps,npage", [
     (2, 8, 2, 64, 16, 8, 32),
     (4, 4, 4, 128, 32, 4, 16),
     (2, 8, 1, 64, 16, 6, 12),   # MQA
     (1, 16, 8, 128, 8, 16, 16),
+    (5, 8, 2, 64, 16, 48, 240),     # 3 blocks a row
+    (3, 4, 4, 128, 8, 100, 300),    # 4 blocks, the last one padded
 ])
 @pytest.mark.parametrize("stacked", [None, "bf16", "int8"])
 def test_paged_attention(b, h, hkv, d, page, pps, npage, stacked):
     """The 4-D call matches its oracle.  ``stacked``: the layer-indexed
     call on stacked ``[L, P, page, Hkv, D]`` pages (the serving steps'
-    form) equals the 4-D call on slice ``[li]``, for a middle and the
-    last layer, with not-owned (negative) table entries and stats."""
+    form) equals the 4-D call on slice ``[li]``, for the first, a middle
+    and the last layer, with not-owned (negative) table entries inside
+    live blocks and stats."""
     ks = jax.random.split(KEY, 5)
     q = jax.random.normal(ks[0], (b, h, d), jnp.float32)
     pt = jax.random.permutation(ks[3], npage)[:b * pps].reshape(
         b, pps).astype(jnp.int32)
-    lens = jax.random.randint(ks[4], (b,), 1, pps * page + 1, jnp.int32)
+    lens = _lengths(ks[4], b, page, pps)
     if stacked is None:
         kp = jax.random.normal(ks[1], (npage, page, hkv, d), jnp.float32)
         vp = jax.random.normal(ks[2], (npage, page, hkv, d), jnp.float32)
         out = ops.paged_attention(q, kp, vp, pt, lens)
         expect = ref.paged_attention_ref(q, kp, vp, pt, lens)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                                   atol=2e-5, rtol=2e-5)
+        _assert_rows(out, expect, lens, 2e-5)
         return
     from repro.kernels.paged_attention import (paged_attention as pa,
                                                paged_attention_q8 as pa8)
@@ -76,7 +107,7 @@ def test_paged_attention(b, h, hkv, d, page, pps, npage, stacked):
             return pa(q.astype(jnp.bfloat16), *pages, table, lens,
                       layer=li, interpret=True, return_stats=True)
         pages = (kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16))
-    for li in (1, n_layers - 1):
+    for li in (0, 1, n_layers - 1):
         got = call(jnp.int32(li), *pages)
         want = call(None, *(p[li] for p in pages))
         for g, w in zip(got, want):
@@ -155,6 +186,8 @@ def test_rwkv_scan_matches_model_chunked():
 @pytest.mark.parametrize("b,h,hkv,d,page,pps,npage", [
     (2, 8, 2, 64, 16, 8, 32),
     (4, 4, 4, 128, 32, 4, 16),
+    (5, 8, 2, 64, 16, 48, 240),     # 3 blocks a row
+    (3, 4, 4, 128, 8, 100, 300),    # 4 blocks, the last one padded
 ])
 def test_paged_attention_q8(b, h, hkv, d, page, pps, npage):
     """int8-KV paged kernel (the §Perf opt-2 realization): matches its
@@ -168,35 +201,52 @@ def test_paged_attention_q8(b, h, hkv, d, page, pps, npage):
     vq, vsc = quantize_kv(vp_f)
     pt = jax.random.permutation(ks[3], npage)[:b * pps].reshape(
         b, pps).astype(jnp.int32)
-    lens = jax.random.randint(ks[4], (b,), 1, pps * page + 1, jnp.int32)
+    lens = _lengths(ks[4], b, page, pps)
     out = ops.paged_attention_q8(q, kq, vq, ksc, vsc, pt, lens)
     oracle = ref.paged_attention_q8_ref(q, kq, vq, ksc, vsc, pt, lens)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
-                               atol=2e-5, rtol=2e-5)
+    _assert_rows(out, oracle, lens, 2e-5)
     fp = ref.paged_attention_ref(q, kp_f, vp_f, pt, lens)
-    assert float(jnp.abs(out - fp).max()) < 0.05   # quantization noise only
+    live = np.asarray(lens) > 0
+    # quantization noise only
+    assert float(jnp.abs(out - fp)[live].max()) < 0.05
 
 
+@pytest.mark.parametrize("pps", [4, 40])
 @pytest.mark.parametrize("quantized", [False, True])
-def test_paged_attention_stats_match_partial_contract(quantized):
+def test_paged_attention_stats_match_partial_contract(quantized, pps):
     """``return_stats`` yields the ``(o, m, l)`` partial contract of
     ``runtime.serve.paged_attention_partial`` (the pool's per-node
-    attention), and a negative page id is skipped as not owned."""
+    attention), and a negative page id is skipped as not owned.  At 40
+    pages (3 blocks of the kernel, the last padded) the rows end on a
+    block edge, mid-block and mid-page, and one is empty."""
     from repro.kernels.paged_attention import (paged_attention as pa,
-                                               paged_attention_q8 as pa8)
+                                               paged_attention_q8 as pa8,
+                                               pages_per_block)
     from repro.models.layers import quantize_kv
     from repro.runtime.serve import paged_attention_partial
-    b, h, hkv, d, page, pps, npage = 3, 8, 2, 64, 16, 4, 16
+    h, hkv, d, page = 8, 2, 64, 16
+    stripe = [i % 2 == 0 for i in range(pps)]
+    if pps == 4:
+        lens = [pps * page, 37, 20]
+        # row 0 owns every page, row 1 a striped subset, row 2 none
+        owned = [[True] * pps, stripe, [False] * pps]
+    else:
+        bk = pages_per_block(pps, page) * page
+        lens = [bk, bk + bk // 2 + 3, 0, pps * page, 2 * bk - 8]
+        # rows 1 and 3 own every other page; row 4 all but the one at
+        # its second block's start
+        owned = [[True] * pps, stripe, [True] * pps, stripe,
+                 [i != bk // page for i in range(pps)]]
+    b = len(lens)
+    npage = b * pps
     ks = jax.random.split(KEY, 6)
     q = jax.random.normal(ks[0], (b, h, d), jnp.float32)
     kp = jax.random.normal(ks[1], (npage, page, hkv, d), jnp.float32)
     vp = jax.random.normal(ks[2], (npage, page, hkv, d), jnp.float32)
     pt = jax.random.permutation(ks[3], npage)[:b * pps].reshape(
         b, pps).astype(jnp.int32)
-    lens = jnp.asarray([pps * page, 37, 20], jnp.int32)
-    # row 0 owns every page, row 1 a striped subset, row 2 none
-    owned = jnp.asarray([[True] * pps, [True, False, True, False],
-                         [False] * pps])
+    lens = jnp.asarray(lens, jnp.int32)
+    owned = jnp.asarray(owned)
     table = jnp.where(owned, pt, -1)
     if quantized:
         kq, ksc = quantize_kv(kp)

@@ -62,6 +62,22 @@ def test_paged_attention_compiles(one_chip, dtype):
              ((B, PPS), jnp.int32), ((B,), jnp.int32))
 
 
+@pytest.mark.parametrize("rows,pps", [(8, 256), (1024, 64)])
+def test_paged_attention_stacked_compiles(one_chip, rows, pps):
+    """The kernel as granite-decode-batch calls it: the 40-layer stacked
+    store of the cell's compiled window (2,800 pages) read at a traced
+    layer, by the decode horizon (8 rows over a 256-page table: 16
+    blocks a row) and by a prefill chunk (1,024 rows over 64 pages).
+    No VMEM limit is raised, so it compiles within the default scoped
+    VMEM or not at all."""
+    store = ((40, 2800, PAGE, HKV, D), jnp.bfloat16)
+    _compile(lambda q, k, v, t, n, li: paged_attention(q, k, v, t, n,
+                                                       layer=li),
+             one_chip, ((rows, H, D), jnp.bfloat16), store, store,
+             ((rows, pps), jnp.int32), ((rows,), jnp.int32),
+             ((), jnp.int32))
+
+
 @pytest.mark.parametrize("code", ["int8", "float8_e4m3fn"])
 def test_paged_attention_q8_compiles(one_chip, code):
     page = ((N_PAGES, PAGE, HKV, D), jnp.dtype(code))

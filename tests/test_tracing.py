@@ -69,9 +69,12 @@ def test_one_iteration_opens_the_span_tree(tiny):
     by = {r.name: r for r in recs}
     assert by["server.prefill"].counts == {"tokens": 6, "final": True}
     hz = by["server.horizon"].counts
-    assert set(hz) == {"pages", "bucket_rows", "table_width"}
-    # 6 prompt tokens + 8 horizon steps on pages of 4
+    assert set(hz) == {"pages", "bucket_rows", "table_width", "blocks",
+                       "block_grid"}
+    # 6 prompt tokens + 8 horizon steps on pages of 4, one block of the
+    # kernel's grid
     assert hz["pages"] == 4 <= hz["bucket_rows"] * hz["table_width"]
+    assert hz["blocks"] == 1 <= hz["block_grid"] == hz["bucket_rows"]
     for name in ("scheduler.iteration", "scheduler.admit",
                  "scheduler.retire"):
         assert all(r.counts == {} for r in recs if r.name == name)
@@ -92,6 +95,8 @@ def test_counts_match_what_the_server_did(tiny):
     assert hz
     for c in hz:
         assert 0 < c["pages"] <= c["bucket_rows"] * c["table_width"]
+        assert 0 < c["blocks"] <= c["block_grid"] <= c["bucket_rows"] * \
+            c["table_width"]
 
 
 def test_log_drops_the_oldest_and_counts_them():
